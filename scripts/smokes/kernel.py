@@ -43,7 +43,7 @@ from repro import solvers  # noqa: E402
 from repro.data import linsys  # noqa: E402
 from repro.kernels import block_projection as bp  # noqa: E402
 from repro.kernels import ops, ref  # noqa: E402
-from repro.launch.mesh import make_compat_mesh  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.solvers import FactorStore, LinsysServer  # noqa: E402
 
 PROJ = ("apc", "consensus", "cimmino")
@@ -90,7 +90,7 @@ def smoke_raw_ops():
 def smoke_solver_paths():
     assert len(jax.devices()) == 4, jax.devices()
     sys_ = linsys.conditioned_gaussian(n=96, m=4, cond=10.0, seed=3)
-    mesh = make_compat_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     Bk = np.random.default_rng(4).standard_normal((5, sys_.N))
     for name in PROJ:
         s = solvers.get(name)
@@ -153,7 +153,7 @@ def smoke_sparse_paths():
     # end-to-end: silent sparse dispatch + fused-residual history parity
     import warnings
     sys_ = linsys.banded_system(n=192, m=4, bandwidth=6, seed=0)
-    mesh = make_compat_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     for name in ("apc", "cimmino"):
         s = solvers.get(name)
         prm = s.resolve_params(sys_)

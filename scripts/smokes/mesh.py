@@ -17,14 +17,14 @@ import numpy as np  # noqa: E402
 
 from repro import solvers  # noqa: E402
 from repro.data import linsys  # noqa: E402
-from repro.launch.mesh import make_compat_mesh  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 def main():
     t0 = time.time()
     assert len(jax.devices()) == 4, jax.devices()
     sys_ = linsys.conditioned_gaussian(n=64, m=4, cond=10.0, seed=3)
-    mesh = make_compat_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     for name in solvers.available():
         s = solvers.get(name)
         prm = s.resolve_params(sys_)

@@ -21,7 +21,7 @@ import numpy as np  # noqa: E402
 
 from repro import solvers  # noqa: E402
 from repro.data import linsys  # noqa: E402
-from repro.launch.mesh import make_compat_mesh  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.solvers import (AsyncLinsysServer, CapabilityError,  # noqa: E402
                            FactorStore, LinsysServer, solve_stream)
 
@@ -119,7 +119,7 @@ def stream_scenario():
 def main():
     t0 = time.time()
     assert len(jax.devices()) == 4, jax.devices()
-    mesh = make_compat_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     lines = [sparse_scenario(mesh), ls_scenario(mesh), stream_scenario()]
     for ln in lines:
         print("  " + ln)

@@ -2,7 +2,7 @@
 
 ``kernels.block_projection.default_interpret()`` is the single
 authority on interpret-vs-compile (TPU detection + the
-``REPRO_PALLAS_INTERPRET`` override CI's force-compile lane relies on).
+``REPRO_PALLAS_INTERPRET`` override).
 A ``pl.pallas_call`` with a hard-coded ``interpret=True``/``False`` —
 or with no ``interpret`` argument at all, which silently means
 ``False`` — pins one mode and breaks either the CPU test environment or

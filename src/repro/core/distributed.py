@@ -27,11 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax.shard_map is the stable spelling on newer releases
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from .partition import BlockSystem
 
 
@@ -85,7 +80,7 @@ class ShardedAPC:
             return st.x, st.xbar
 
         sp = self.specs()
-        return jax.jit(_shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(sp["A"], sp["chol"], sp["x"], sp["xbar"]),
             out_specs=(sp["x"], sp["xbar"]),
@@ -101,7 +96,7 @@ class ShardedAPC:
             return residual_shard(A, b, xbar, b_norm, ctx)
 
         sp = self.specs()
-        return jax.jit(_shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(sp["A"], sp["b"], sp["xbar"]),
             out_specs=P(),
@@ -138,7 +133,7 @@ def prepare_on_mesh(solver: ShardedAPC, sys: BlockSystem):
         st = apc.mesh_init(factors, b, prm, ctx)
         return factors.chol, st.x, st.xbar
 
-    setup_fn = jax.jit(_shard_map(
+    setup_fn = jax.jit(jax.shard_map(
         setup, mesh=mesh, in_specs=(sp["A"], sp["b"]),
         out_specs=(sp["chol"], sp["x"], sp["xbar"])))
 

@@ -17,7 +17,8 @@ Interpret vs compiled is decided at trace time from the runtime backend
 (compiled on real TPU, interpret everywhere else); override with the
 ``REPRO_PALLAS_INTERPRET=0/1`` env var or an explicit ``interpret=`` kwarg
 (see ``block_projection.default_interpret``).  The CI kernel smoke runs
-every path under ``=1`` each push and force-compiles with ``=0`` on lanes
-where lowering is available.
+every path under ``=1`` each push; ``tests/test_tpu_compile.py`` compiles
+the kernels for a described TPU v5e chip, and ``chip_smoke.py`` runs them
+on one.
 """
 from . import ops, ref  # noqa: F401

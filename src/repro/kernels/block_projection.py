@@ -24,11 +24,11 @@ Tiling: three axes are cut independently.  The n axis streams in
 lane-aligned BN tiles (multiple of 128); the p axis and the k batch may be
 cut into BP / BK sublane tiles (multiples of 8) when they outgrow VMEM —
 by default both stay whole (p ≪ n by construction and k is a serving
-batch), reproducing the original single-residency schedule.  A tile of A
-(BP × BN) occupies BP·BN·4 bytes ≤ ~2 MB for BP ≤ 512, well inside the
-~16 MB VMEM budget, and its (BK, BN)·(BN, BP) MXU work is aligned when
-BK, BP, BN are multiples of (8, 8, 128).  All three tiles are autotuned by
-``ops.pick_tiles`` (measured, cached per (k, p, n, dtype), pins
+batch), reproducing the original single-residency schedule, as long as
+the double-buffered tiles fit the compiler's scoped VMEM (16 MiB on v5e:
+an f32 (2048, 512) tile of A takes 4 MiB per buffer; a (4096, 512) one
+does not fit).  All three tiles are chosen by ``ops.pick_tiles`` (VMEM-
+budgeted, measured on TPU, cached per (k, p, n, dtype), pins
 ``REPRO_KERNEL_BN`` / ``REPRO_KERNEL_BP`` / ``REPRO_KERNEL_BK``).
 
 Accumulation dtype follows the *compute* operand (x / x̄ / u), not the
@@ -67,10 +67,16 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 DEFAULT_BN = 512          # lane-axis tile; multiple of 128
+_I0 = np.int32(0)         # a constant block index that stays int32
+# f32 contractions at full precision: the MXU default is one bf16 pass,
+# which measured 3.4e-3 relative error for the fused update on a v5e chip
+_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def default_interpret() -> bool:
@@ -78,11 +84,10 @@ def default_interpret() -> bool:
 
     On a real TPU the kernels compile (interpret=False); everywhere else
     (CPU containers, GPU hosts) they run in interpret mode.  The env var
-    ``REPRO_PALLAS_INTERPRET=0/1`` overrides both — e.g. force-compile on
-    a TPU-less CI to catch lowering regressions, or force interpret on TPU
-    while bisecting a numerics issue.  Resolved when a kernel first traces
-    for a given shape; it is not a per-call toggle (pass ``interpret=``
-    explicitly for that).
+    ``REPRO_PALLAS_INTERPRET=0/1`` overrides both — e.g. force interpret
+    on TPU while bisecting a numerics issue.  Resolved when a kernel
+    first traces for a given shape; it is not a per-call toggle (pass
+    ``interpret=`` explicitly for that).
     """
     env = os.environ.get("REPRO_PALLAS_INTERPRET")
     if env is not None:
@@ -112,7 +117,7 @@ def _gather_kernel(x_ref, xbar_ref, a_ref, u_ref, *, acc_dtype):
     a = a_ref[...].astype(acc_dtype)                        # (BP, BN)
     # (BK, BN) @ (BN, BP) on the MXU; accumulate in acc_dtype.
     u_ref[...] += jax.lax.dot_general(
-        d, a, (((1,), (1,)), ((), ())),
+        d, a, (((1,), (1,)), ((), ())), precision=_PRECISION,
         preferred_element_type=acc_dtype).astype(u_ref.dtype)
 
 
@@ -131,7 +136,7 @@ def _scatter_kernel(x_ref, xbar_ref, b_ref, u_ref, g_ref, y_ref, *,
     u = u_ref[...].astype(acc_dtype)                        # (BK, BP)
     b = b_ref[...].astype(acc_dtype)                        # (BN, BP)
     bu = jax.lax.dot_general(
-        u, b, (((1,), (1,)), ((), ())),
+        u, b, (((1,), (1,)), ((), ())), precision=_PRECISION,
         preferred_element_type=acc_dtype)                   # (BK, BN)
     y = y_ref[...].astype(acc_dtype) - gamma * bu
     y_ref[...] = y.astype(y_ref.dtype)
@@ -148,7 +153,7 @@ def _cim_gather_kernel(xbar_ref, a_ref, u_ref, *, acc_dtype):
     xb = xbar_ref[...].astype(acc_dtype)                    # (BK, BN)
     a = a_ref[...].astype(acc_dtype)                        # (BP, BN)
     u_ref[...] += jax.lax.dot_general(
-        xb, a, (((1,), (1,)), ((), ())),
+        xb, a, (((1,), (1,)), ((), ())), precision=_PRECISION,
         preferred_element_type=acc_dtype).astype(u_ref.dtype)
 
 
@@ -163,7 +168,7 @@ def _cim_scatter_kernel(v_ref, b_ref, r_ref, *, acc_dtype):
     v = v_ref[...].astype(acc_dtype)                        # (BK, BP)
     b = b_ref[...].astype(acc_dtype)                        # (BN, BP)
     r = jax.lax.dot_general(
-        v, b, (((1,), (1,)), ((), ())),
+        v, b, (((1,), (1,)), ((), ())), precision=_PRECISION,
         preferred_element_type=acc_dtype)                   # (BK, BN)
     r_ref[...] = (r_ref[...].astype(acc_dtype) + r).astype(r_ref.dtype)
 
@@ -233,7 +238,10 @@ def apc_scatter(B, x, xbar, u, gamma, *, bn: int = DEFAULT_BN,
             pl.BlockSpec((bk, bn), lambda i, j, l: (i, j)),   # xbar
             pl.BlockSpec((bn, bp), lambda i, j, l: (j, l)),   # B
             pl.BlockSpec((bk, bp), lambda i, j, l: (i, l)),   # U
-            pl.BlockSpec((1, 1), lambda i, j, l: (0, 0)),     # gamma scalar
+            # gamma scalar in SMEM; int32 block indices, as python ints
+            # would trace to int64 under jax_enable_x64 and not lower
+            pl.BlockSpec((1, 1), lambda i, j, l: (_I0, _I0),
+                         memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((bk, bn), lambda i, j, l: (i, j)),
         out_shape=jax.ShapeDtypeStruct((k, n), x.dtype),

@@ -27,10 +27,13 @@ actual gather+scatter pair — BN lane tiles via ``pick_bn`` (cached per
 (p, n_pad, dtype), the original search), then p-/k-sublane tiles staged at
 the winning BN (cached per (k, p, n, dtype)).  The measurement runs where
 the kernels actually compile (skipped in interpret mode — interpret
-timings say nothing about HBM traffic); force it with
-``REPRO_KERNEL_AUTOTUNE=1``, disable with ``=0``, or pin tiles outright
-with ``REPRO_KERNEL_BN=256`` / ``REPRO_KERNEL_BP=64`` /
-``REPRO_KERNEL_BK=8``.
+timings say nothing about HBM traffic).  Only tiles the TPU compiler
+accepts are ever candidates or defaults: blocks obey the (8, 128) rule
+and fit ``VMEM_BUDGET`` (``tile_vmem_bytes``), so where the whole p axis
+does not fit, the default is the largest fitting p tile.  Force the
+measurement with ``REPRO_KERNEL_AUTOTUNE=1``, disable it with ``=0``, or
+pin tiles outright with ``REPRO_KERNEL_BN=256`` / ``REPRO_KERNEL_BP=128``
+/ ``REPRO_KERNEL_BK=8``.
 
 Sparse systems get the same fused engine over the compressed support:
 ``sparse_proj_update`` / ``sparse_cimmino_update`` run the (p, w) vals /
@@ -66,12 +69,17 @@ _BN_CACHE: dict = {}
 # (k_pad, p_pad, n_pad, dtype-name) -> measured (bp, bk) sublane tiles
 _TILE_CACHE: dict = {}
 # candidate lane tiles, measured in this order; the heuristic fallback is
-# the FIRST candidate dividing n_pad (preserving the old _pick_bn choice)
+# the FIRST fitting candidate dividing n_pad (512 wherever it fits)
 BN_CANDIDATES = (bp.DEFAULT_BN, 1024, 256, 128)
-# candidate p-/k-sublane tiles (whole-axis — the original single-residency
-# schedule — is always the first candidate and the no-autotune fallback)
-BP_CANDIDATES = (256, 128, 64, 32, 16, 8)
+# candidate p-/k-tiles below the default.  A TPU block's last two dims
+# are multiples of (8, 128) or span the whole axis: p is the lane dim of
+# the B and U blocks, so a cut p tile is a 128-multiple; k is a sublane
+# dim, so a cut k tile is an 8-multiple
+BP_CANDIDATES = (512, 256, 128)
 BK_CANDIDATES = (32, 16, 8)
+# scoped VMEM one kernel's pipelined blocks may take: the v5e compiler's
+# default limit is 16 MiB, less a margin for its internal scratch
+VMEM_BUDGET = 14 * 2**20
 
 
 def _pad_axis(a, axis: int, mult: int):
@@ -82,6 +90,10 @@ def _pad_axis(a, axis: int, mult: int):
     pads = [(0, 0)] * a.ndim
     pads[axis] = (0, rem)
     return jnp.pad(a, pads), size
+
+
+def _pad_to(size: int, mult: int) -> int:
+    return size + (-size) % mult
 
 
 def _rows(x):
@@ -110,6 +122,50 @@ def bn_cache() -> dict:
     return dict(_BN_CACHE)
 
 
+def tile_vmem_bytes(bn: int, bp_: int, bk: int, dtype) -> int:
+    """VMEM one grid step of the larger kernel of a pair (the APC
+    scatter) holds: every block double-buffered — the (BN, BP) A/B tile
+    in the stored dtype, plus the x, x̄, output (BK, BN) rows and the
+    (BK, BP) U block in the compute dtype (f32 at least) — and one
+    working copy of two row blocks and of U for the full-precision MXU
+    passes, each padded to the (8, 128) tile.  Checked against the v5e
+    compiler: every shape it counts within ``VMEM_BUDGET`` compiled."""
+    a = np.dtype(dtype).itemsize
+    c = max(a, 4)
+    rows, lanes = _pad_to(bk, 8), _pad_to(bp_, 128)
+    blocks = bn * lanes * a + 3 * rows * bn * c + rows * lanes * c
+    return 2 * blocks + 2 * rows * bn * c + rows * lanes * c
+
+
+def tile_fits(bn: int, bp_: int, bk: int, dtype) -> bool:
+    return tile_vmem_bytes(bn, bp_, bk, dtype) <= VMEM_BUDGET
+
+
+def _p_tiles(p_pad: int):
+    """Legal p tiles, largest first: the whole axis, then every
+    128-multiple that divides it."""
+    return [p_pad] + [t for t in range((p_pad - 1) // 128 * 128, 0, -128)
+                      if p_pad % t == 0]
+
+
+def _k_tiles(k_pad: int):
+    """Legal k tiles, largest first: the whole batch, then the smaller
+    8-multiple candidates that divide it."""
+    return [k_pad] + [c for c in BK_CANDIDATES
+                      if c < k_pad and k_pad % c == 0]
+
+
+def default_tiles(bn: int, p_pad: int, k_pad: int, dtype):
+    """The (bp, bk) schedule without measurement: whole axes where they
+    fit VMEM, else the whole batch with the largest fitting p tile, else
+    cut k too.  None when nothing fits at this BN."""
+    for bk in _k_tiles(k_pad):
+        for bpp in _p_tiles(p_pad):
+            if tile_fits(bn, bpp, bk, dtype):
+                return bpp, bk
+    return None
+
+
 def _autotune_enabled(interpret: bool) -> bool:
     env = os.environ.get(AUTOTUNE_ENV)
     if env is not None:
@@ -120,30 +176,13 @@ def _autotune_enabled(interpret: bool) -> bool:
 
 
 def _measure_bn(p_pad: int, n_pad: int, dtype, cands, interpret: bool) -> int:
-    """Time the gather+scatter pair per candidate tile; smallest wins.
-
-    Dummy operands, x == x̄ (d = 0 — timing is traffic-bound, not
-    value-dependent); best-of-3 after a compile warmup.
-    """
-    rng = np.random.default_rng(0)
-    A = jnp.asarray(rng.standard_normal((p_pad, n_pad)), dtype)
-    B = jnp.asarray(rng.standard_normal((n_pad, p_pad)), dtype)
-    x = jnp.asarray(rng.standard_normal((8, n_pad)), dtype)
-    u = jnp.asarray(rng.standard_normal((8, p_pad)), dtype)
-    g = jnp.ones((1, 1), dtype)
-    best, best_t = cands[0], float("inf")
-    for bn in cands:
-        def run(bn=bn):
-            uu = bp.apc_gather(A, x, x, bn=bn, interpret=interpret)
-            return bp.apc_scatter(B, x, x, u, g, bn=bn, interpret=interpret)
-        jax.block_until_ready(run())            # compile + warm
-        t0 = time.perf_counter()
-        for _ in range(3):
-            out = run()
-        jax.block_until_ready(out)
-        t = time.perf_counter() - t0
-        if t < best_t:
-            best, best_t = bn, t
+    """Time the gather+scatter pair per candidate lane tile, each at its
+    default (bp, bk) for an 8-row batch; fastest wins (ties: earliest)."""
+    times = {bn: _measure_pair(p_pad, n_pad, 8, dtype, bn,
+                               *default_tiles(bn, p_pad, 8, dtype),
+                               interpret)
+             for bn in cands}
+    best = min(cands, key=times.__getitem__)
     log.debug("autotuned BN=%d for (p=%d, n=%d, %s) in %d candidates",
               best, p_pad, n_pad, np.dtype(dtype).name, len(cands))
     return best
@@ -169,7 +208,10 @@ def pick_bn(n_pad: int, p_pad: int = 8, dtype=jnp.float32, *,
     hit = _BN_CACHE.get(key)
     if hit is not None:
         return hit
-    cands = [c for c in BN_CANDIDATES if n_pad % c == 0] or [128]
+    # only tiles some legal (bp, bk) schedule fits in VMEM — a candidate
+    # the compiler refuses is never measured, never the default
+    cands = [c for c in BN_CANDIDATES if n_pad % c == 0
+             and default_tiles(c, p_pad, 1, dtype) is not None] or [128]
     if len(cands) == 1 or not _autotune_enabled(interpret):
         bn = cands[0]
     else:
@@ -223,19 +265,22 @@ def _measure_pair(p_pad, n_pad, k_pad, dtype, bn, bpp, bk, interpret):
     return time.perf_counter() - t0
 
 
-def _measure_tiles(k_pad, p_pad, n_pad, dtype, bn, interpret):
-    """Staged (bp, bk) search at the already-chosen BN: measure the p-tile
-    candidates at whole-k, then the k-tile candidates at the winning
-    p-tile — O(|BP| + |BK|) timings instead of the full cross product."""
-    best_bp, best_t = p_pad, _measure_pair(
-        p_pad, n_pad, k_pad, dtype, bn, p_pad, k_pad, interpret)
-    for c in (c for c in BP_CANDIDATES if c < p_pad and p_pad % c == 0):
-        t = _measure_pair(p_pad, n_pad, k_pad, dtype, bn, c, k_pad,
+def _measure_tiles(k_pad, p_pad, n_pad, dtype, bn, default, interpret):
+    """Staged (bp, bk) search at the already-chosen BN: measure the
+    default, then the smaller fitting p-tile candidates at its k tile,
+    then the smaller k-tile candidates at the winning p tile —
+    O(|BP| + |BK|) timings instead of the full cross product."""
+    best_bp, best_bk = default
+    best_t = _measure_pair(p_pad, n_pad, k_pad, dtype, bn, best_bp, best_bk,
+                           interpret)
+    for c in [c for c in BP_CANDIDATES if c < best_bp and p_pad % c == 0
+              and tile_fits(bn, c, best_bk, dtype)]:
+        t = _measure_pair(p_pad, n_pad, k_pad, dtype, bn, c, best_bk,
                           interpret)
         if t < best_t:
             best_bp, best_t = c, t
-    best_bk = k_pad
-    for c in (c for c in BK_CANDIDATES if c < k_pad and k_pad % c == 0):
+    for c in [c for c in _k_tiles(k_pad)[1:] if c < best_bk
+              and tile_fits(bn, best_bp, c, dtype)]:
         t = _measure_pair(p_pad, n_pad, k_pad, dtype, bn, best_bp, c,
                           interpret)
         if t < best_t:
@@ -253,9 +298,10 @@ def pick_tiles(n_pad: int, p_pad: int = 8, k_pad: int = 1,
     BN comes from ``pick_bn`` (env pin > cache > measurement — the
     original lane-tile search, cache format unchanged); the p-/k-sublane
     tiles resolve env pin (``REPRO_KERNEL_BP`` / ``REPRO_KERNEL_BK``) >
-    cache > staged measurement at the winning BN > whole-axis default
-    (the original single-residency schedule).  Called at trace time, so
-    the choice is baked into each compiled executor.
+    cache > staged measurement at the winning BN > ``default_tiles``
+    (whole axes wherever they fit VMEM — the original single-residency
+    schedule).  Called at trace time, so the choice is baked into each
+    compiled executor.
     """
     bn = pick_bn(n_pad, p_pad, dtype, interpret=interpret)
     bpp = _env_tile(BP_ENV, p_pad, "p")
@@ -265,11 +311,15 @@ def pick_tiles(n_pad: int, p_pad: int = 8, k_pad: int = 1,
     key = (int(k_pad), int(p_pad), int(n_pad), np.dtype(dtype).name)
     hit = _TILE_CACHE.get(key)
     if hit is None:
+        # nothing fits only at a BN too wide for VMEM (pinned, or the
+        # last-resort 128): keep the whole axes; the compiler says so
+        default = (default_tiles(bn, key[1], key[0], dtype)
+                   or (key[1], key[0]))
         if _autotune_enabled(interpret) and (p_pad > 8 or k_pad > 8):
             hit = _measure_tiles(key[0], key[1], key[2], np.dtype(dtype),
-                                 bn, interpret)
+                                 bn, default, interpret)
         else:
-            hit = (int(p_pad), int(k_pad))
+            hit = default
         _TILE_CACHE[key] = hit
     return bn, (bpp if bpp is not None else hit[0]), \
         (bk if bk is not None else hit[1])
@@ -314,10 +364,6 @@ def engine_cache_clear() -> None:
 def engine_cache() -> dict:
     """The live engine-choice cache (read-only use)."""
     return dict(_ENGINE_CACHE)
-
-
-def _pad_to(size: int, mult: int) -> int:
-    return size + (-size) % mult
 
 
 _MEAS_WORKERS = 2   # dummy worker axis the engine measurement vmaps over

@@ -17,21 +17,30 @@ Block Cimmino (row projection):
 Every oracle is batch-polymorphic exactly like the kernels: row-vector
 operands may carry a leading (k,) RHS axis (einsum '...' broadcasting), so
 one reference covers the single-RHS and the multi-RHS kernel paths.
+
+The dense oracles evaluate in numpy when every operand is a numpy array,
+so a float64 host reference stays float64 whatever JAX's x64 setting.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
+
+
+def _einsum(spec, *ops):
+    xp = np if all(isinstance(o, np.ndarray) for o in ops) else jnp
+    return xp.einsum(spec, *ops)
 
 
 def apc_gather_ref(A, x, xbar):
     """u = A (xbar - x).   A (p, n); x, xbar (n,) or (k, n)."""
-    return jnp.einsum("pn,...n->...p", A, xbar - x)
+    return _einsum("pn,...n->...p", A, xbar - x)
 
 
 def apc_scatter_ref(B, x, xbar, u, gamma):
     """y = x + gamma * ((xbar - x) - B u).   B (n, p); u (p,) or (k, p)."""
     d = xbar - x
-    return x + gamma * (d - jnp.einsum("np,...p->...n", B, u))
+    return x + gamma * (d - _einsum("np,...p->...n", B, u))
 
 
 def block_projection_ref(A, B, x, xbar, gamma):
@@ -43,12 +52,12 @@ def block_projection_ref(A, B, x, xbar, gamma):
 
 def cimmino_gather_ref(A, xbar):
     """u = A xbar.   A (p, n); xbar (n,) or (k, n)."""
-    return jnp.einsum("pn,...n->...p", A, xbar)
+    return _einsum("pn,...n->...p", A, xbar)
 
 
 def cimmino_scatter_ref(B, v):
     """r = B v.   B (n, p); v (p,) or (k, p)."""
-    return jnp.einsum("np,...p->...n", B, v)
+    return _einsum("np,...p->...n", B, v)
 
 
 def cimmino_update_ref(A, B, b, xbar):

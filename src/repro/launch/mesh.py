@@ -6,40 +6,28 @@ state, so tests/benches that want a single CPU device can import it safely.
 Production target: TPU v5e pods, 256 chips each, mesh (16 data, 16 model);
 multi-pod doubles up with a leading "pod" axis used as a second data-
 parallel axis (DP across DCN, TP kept inside the pod ICI domain).
-
-Explicit axis types (``jax.sharding.AxisType``) only exist on newer JAX
-releases; on older installs ``make_compat_mesh`` silently falls back to the
-default (auto) axis semantics so every driver keeps importing and running.
 """
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # AxisType landed after jax 0.4.x — optional on the installed runtime
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 SINGLE_POD = (16, 16)
 MULTI_POD = (2, 16, 16)
 
 
-def make_compat_mesh(shape, axes) -> Mesh:
-    """``jax.make_mesh`` with explicit Auto axis types when supported."""
-    if AxisType is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(AxisType.Auto,) * len(shape))
-        except TypeError:  # make_mesh predates the axis_types kwarg
-            pass
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with Auto axes: ``shard_map`` and the sharding
+    rules here place arrays themselves (``make_mesh`` defaults to
+    Explicit axes)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = MULTI_POD if multi_pod else SINGLE_POD
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_compat_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
@@ -47,12 +35,12 @@ def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
     n = len(jax.devices())
     data = min(data, n)
     model = min(model, n // data)
-    return make_compat_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def solver_mesh(workers: int, model: int = 1) -> Mesh:
     """Mesh for the solver backend: 'data' = workers, 'model' = col shards."""
-    return make_compat_mesh((workers, model), ("data", "model"))
+    return make_mesh((workers, model), ("data", "model"))
 
 
 def solver_mesh_for(workers: int, model: int = 1) -> Mesh:
@@ -66,4 +54,4 @@ def solver_mesh_for(workers: int, model: int = 1) -> Mesh:
     budget = max(1, len(jax.devices()) // max(1, model))
     data = max(d for d in range(1, workers + 1)
                if workers % d == 0 and d <= budget)
-    return make_compat_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

@@ -34,6 +34,7 @@ import numpy as np
 
 from repro import solvers
 from repro.data import linsys
+from repro.launch import cache
 from repro.solvers.capability import ExecutionPlan
 from repro.solvers.pipeline import AsyncLinsysServer, Shed
 from repro.solvers.serve import LinsysServer
@@ -80,6 +81,7 @@ def main(argv=None):
                          "mode; overflow requests are shed explicitly")
     args = ap.parse_args(argv)
 
+    cache.enable_compile_cache()
     jax.config.update("jax_enable_x64", args.x64)
     store = FactorStore(capacity=args.store_capacity,
                         directory=args.store_dir)

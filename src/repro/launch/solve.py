@@ -30,7 +30,7 @@ from repro import solvers
 from repro.core import spectral
 from repro.checkpoint import ckpt
 from repro.data import linsys
-from repro.launch import mesh as mesh_lib
+from repro.launch import cache, mesh as mesh_lib
 
 
 def main(argv=None):
@@ -68,6 +68,7 @@ def main(argv=None):
                          "resulting dtypes — resume with the same setting)")
     args = ap.parse_args(argv)
 
+    cache.enable_compile_cache()
     jax.config.update("jax_enable_x64", args.x64)
     sys_ = linsys.ALL_PROBLEMS[args.problem](seed=args.seed)
     # re-partition to the requested worker count, preserving the system's

@@ -38,11 +38,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax.shard_map is the stable spelling on newer releases
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map
-
 from repro.core import blockops
 from repro.core.partition import BlockSystem
 
@@ -216,7 +211,7 @@ def _place(solver, sys: BlockSystem, ctx: MeshContext, prm, factors,
                                                    use_kernel=True))
                    if use_kernel
                    else (lambda A_: solver.mesh_prepare(A_, prm, ctx)))
-        prep = jax.jit(shard_map(
+        prep = jax.jit(jax.shard_map(
             prep_fn, mesh=mesh, in_specs=(A_spec,), out_specs=fspecs))
         factors = prep(A)
         if store is not None:
@@ -270,7 +265,7 @@ def compile_solve(solver, sys: BlockSystem, *, mesh: Optional[Mesh] = None,
     sspecs = solver.mesh_state_specs(ctx)
 
     if warm_state is None:
-        init_fn = jax.jit(shard_map(
+        init_fn = jax.jit(jax.shard_map(
             lambda f, b_: solver.mesh_init(f, b_, prm, ctx), mesh=mesh,
             in_specs=(fspecs, b_spec), out_specs=sspecs))
         state = init_fn(factors, b)
@@ -352,9 +347,9 @@ def compile_solve(solver, sys: BlockSystem, *, mesh: Optional[Mesh] = None,
 
     # pallas_call has no shard_map replication rule — the kernel path
     # disables the check (the psum contract itself is unchanged)
-    run = jax.jit(shard_map(run_body, mesh=mesh, in_specs=in_specs,
-                            out_specs=(sspecs, P(), P()),
-                            check_rep=not use_kernel))
+    run = jax.jit(jax.shard_map(run_body, mesh=mesh, in_specs=in_specs,
+                                out_specs=(sspecs, P(), P()),
+                                check_vma=not use_kernel))
     return CompiledSolve(run=run, args=args, params=prm,
                          has_errors=xt is not None)
 
@@ -402,9 +397,7 @@ class BatchedRunner(NamedTuple):
     state_specs: Any
 
     def cache_size(self) -> int:
-        sizes = [getattr(f, "_cache_size", lambda: -1)()
-                 for f in (self.init, self.run)]
-        return -1 if any(s < 0 for s in sizes) else sum(sizes)
+        return self.init._cache_size() + self.run._cache_size()
 
 
 def batched_runner(solver, ctx: MeshContext, prm, iters: int,
@@ -432,7 +425,7 @@ def batched_runner(solver, ctx: MeshContext, prm, iters: int,
     fused_residual = (fused_residual and use_kernel and not ls_mode
                       and iters > 0 and solver.supports_fused_residual)
 
-    init_fn = jax.jit(shard_map(
+    init_fn = jax.jit(jax.shard_map(
         lambda f, Bb_: jax.vmap(
             lambda bb: solver.mesh_init(f, bb, prm, ctx))(Bb_),
         mesh=mesh, in_specs=(fspecs, Bb_spec), out_specs=sspecs))
@@ -480,10 +473,10 @@ def batched_runner(solver, ctx: MeshContext, prm, iters: int,
         s_, res = jax.lax.scan(body, s_, None, length=iters)
         return s_, jax.vmap(solver.extract)(s_), res.T         # (k, T)
 
-    run = jax.jit(shard_map(run_body, mesh=mesh,
-                            in_specs=(A_spec, Bb_spec, fspecs, sspecs),
-                            out_specs=(sspecs, P(None, ctx.n), P()),
-                            check_rep=not use_kernel))
+    run = jax.jit(jax.shard_map(run_body, mesh=mesh,
+                                in_specs=(A_spec, Bb_spec, fspecs, sspecs),
+                                out_specs=(sspecs, P(None, ctx.n), P()),
+                                check_vma=not use_kernel))
     return BatchedRunner(init=init_fn, run=run, A_spec=A_spec,
                          Bb_spec=Bb_spec, factor_specs=fspecs,
                          state_specs=sspecs)
@@ -570,7 +563,7 @@ class RedundantRunner:
         A_rep, self._b_rep = put(A_rep, Arep_spec), put(b_rep, brep_spec)
 
         if factors is None:
-            prep = jax.jit(shard_map(
+            prep = jax.jit(jax.shard_map(
                 lambda Ar: red._red_mesh_prepare(solver, Ar, prm, ctx),
                 mesh=mesh, in_specs=(Arep_spec,), out_specs=fspecs))
             self._frep = prep(A_rep)
@@ -579,7 +572,7 @@ class RedundantRunner:
                 solver.red_factors(solver.mesh_factors(factors), assign),
                 fspecs, mesh)
 
-        self._init = jax.jit(shard_map(
+        self._init = jax.jit(jax.shard_map(
             lambda f, br, W0: solver.red_init(f, br, prm, W0, ctx),
             mesh=mesh, in_specs=(fspecs, brep_spec, self._W_spec),
             out_specs=sspecs))
@@ -611,8 +604,9 @@ class RedundantRunner:
             s_, (res, err) = jax.lax.scan(body, s_, Ws_)
             return s_, res, err
 
-        self._run = jax.jit(shard_map(run_body, mesh=mesh, in_specs=in_specs,
-                                      out_specs=(sspecs, P(), P())))
+        self._run = jax.jit(jax.shard_map(run_body, mesh=mesh,
+                                          in_specs=in_specs,
+                                          out_specs=(sspecs, P(), P())))
 
     def init_state(self, warm_state, W_all):
         """Fresh ``red_init`` (warm_state None) or a placed ``red_expand``
@@ -632,6 +626,4 @@ class RedundantRunner:
                          W_seq, *self._xt)
 
     def cache_size(self) -> int:
-        sizes = [getattr(f, "_cache_size", lambda: -1)()
-                 for f in (self._init, self._run)]
-        return -1 if any(s < 0 for s in sizes) else sum(sizes)
+        return self._init._cache_size() + self._run._cache_size()

@@ -288,11 +288,11 @@ class RedundantEngine:
 
     def cache_size(self) -> int:
         """Total jit-cache entries across the engine's compiled callables
-        (-1 when the runtime does not expose cache introspection) — the
-        zero-steady-state-retrace benchmarks assert this stays flat."""
+        — the zero-steady-state-retrace benchmarks assert this stays
+        flat."""
         if self._mesh_runner is not None:
             return self._mesh_runner.cache_size()
-        return getattr(self._run, "_cache_size", lambda: -1)()
+        return self._run._cache_size()
 
 
 def solve_redundant(solver, sys: BlockSystem, *, r: int, iters: int = 1000,

@@ -169,9 +169,7 @@ class _LocalExecutor:
         return self._warm(A, factors, Bb, states)
 
     def cache_size(self) -> int:
-        sizes = [getattr(f, "_cache_size", lambda: -1)()
-                 for f in (self._cold, self._warm)]
-        return -1 if any(s < 0 for s in sizes) else sum(sizes)
+        return self._cold._cache_size() + self._warm._cache_size()
 
 
 class _MeshExecutor:
@@ -371,14 +369,11 @@ class LinsysServer:
         return ex
 
     def jit_cache_size(self) -> int:
-        """Total jit-cache entries across executors (-1 if the running
-        jax cannot report it).  Constant across batches == zero retraces.
-        (Snapshots the executor dict so the async pipeline's assembly
-        thread can add executors while another thread reads this.)"""
-        sizes = [ex.cache_size() for ex in list(self._executors.values())]
-        if not sizes:
-            return 0
-        return -1 if any(s < 0 for s in sizes) else sum(sizes)
+        """Total jit-cache entries across executors.  Constant across
+        batches == zero retraces.  (Snapshots the executor dict so the
+        async pipeline's assembly thread can add executors while another
+        thread reads this.)"""
+        return sum(ex.cache_size() for ex in list(self._executors.values()))
 
     # ----- serving ----------------------------------------------------------
     def _warm_ok(self, ent: _System, Bb: np.ndarray) -> bool:

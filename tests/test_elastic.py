@@ -241,12 +241,12 @@ jax.config.update('jax_enable_x64', True)
 import numpy as np
 from repro import solvers
 from repro.data import linsys
-from repro.launch.mesh import make_compat_mesh
+from repro.launch.mesh import make_mesh
 from repro.runtime.fault import HeartbeatMonitor
 
 assert len(jax.devices()) == 4
 sys_ = linsys.conditioned_gaussian(n=64, m=4, cond=10.0, seed=3)
-mesh = make_compat_mesh((2, 2), ('data', 'model'))
+mesh = make_mesh((2, 2), ('data', 'model'))
 for name in ['apc', 'consensus', 'cimmino']:
     s = solvers.get(name)
     prm = s.resolve_params(sys_)

@@ -228,11 +228,11 @@ jax.config.update("jax_enable_x64", True)
 import numpy as np
 from repro import solvers
 from repro.data import linsys
-from repro.launch.mesh import make_compat_mesh
+from repro.launch.mesh import make_mesh
 
 assert len(jax.devices()) == 4, jax.devices()
 sys_ = linsys.conditioned_gaussian(n=96, m=4, cond=10.0, seed=3)
-mesh = make_compat_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 B = np.random.default_rng(4).standard_normal((5, sys_.N))
 for name in ("apc", "consensus", "cimmino"):
     s = solvers.get(name)

@@ -133,7 +133,7 @@ def test_mesh_rejects_kernel_and_unknown_backend(sys_, mesh):
 
 
 def test_mesh_context_validates_axes(sys_):
-    mesh1 = mesh_lib.make_compat_mesh((1,), ("data",))
+    mesh1 = mesh_lib.make_mesh((1,), ("data",))
     ctx = mesh_backend.make_context(mesh1, sys_)   # model axis: absent -> None
     assert ctx.model_axis is None and ctx.worker_axes == ("data",)
     with pytest.raises(ValueError, match="worker axes"):
@@ -158,11 +158,11 @@ jax.config.update('jax_enable_x64', True)
 import numpy as np
 from repro import solvers
 from repro.data import linsys
-from repro.launch.mesh import make_compat_mesh
+from repro.launch.mesh import make_mesh
 
 assert len(jax.devices()) == 4
 sys_ = linsys.conditioned_gaussian(n=64, m=4, cond=10.0, seed=3)
-mesh = make_compat_mesh((2, 2), ('data', 'model'))
+mesh = make_mesh((2, 2), ('data', 'model'))
 for name in solvers.available():
     s = solvers.get(name)
     prm = s.resolve_params(sys_)

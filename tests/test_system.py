@@ -27,8 +27,8 @@ jax.config.update('jax_enable_x64', True)
 import numpy as np
 from repro.data import linsys
 from repro.core import apc, distributed
-from repro.launch.mesh import make_compat_mesh
-mesh = make_compat_mesh((4, 2), ('data', 'model'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ('data', 'model'))
 sys_ = linsys.conditioned_gaussian(n=128, m=4, cond=20.0, seed=1)
 xbar, res = distributed.solve_on_mesh(mesh, sys_, iters=200)
 ref = apc.solve(sys_, iters=200)
@@ -80,14 +80,14 @@ import jax.numpy as jnp
 from repro.checkpoint import ckpt
 from repro.core import distributed, spectral
 from repro.data import linsys
-from repro.launch.mesh import make_compat_mesh
+from repro.launch.mesh import make_mesh
 from repro.runtime import fault
 
 sys_ = linsys.conditioned_gaussian(n=64, m=4, cond=10.0, seed=5)
 prm = spectral.apc_optimal(*spectral.mu_extremes(spectral.x_matrix(sys_)))
 
 def run(mesh_shape, x, xbar, iters):
-    mesh = make_compat_mesh(mesh_shape, ('data', 'model'))
+    mesh = make_mesh(mesh_shape, ('data', 'model'))
     s = distributed.make_sharded_apc(mesh, gamma=prm.gamma, eta=prm.eta)
     A_, b, chol, x0, xb0 = distributed.prepare_on_mesh(s, sys_)
     step = s.step_fn()
