@@ -14,6 +14,8 @@ import math
 
 import numpy as np
 
+from repro.runtime.spans import span
+
 from .partition import BlockSystem
 
 # ---------------------------------------------------------------------------
@@ -23,19 +25,21 @@ from .partition import BlockSystem
 
 def x_matrix(sys: BlockSystem) -> np.ndarray:
     """X = (1/m) sum_i A_i^T (A_i A_i^T)^{-1} A_i   (n x n, symmetric PSD)."""
-    A = np.asarray(sys.A_blocks, dtype=np.float64)
-    m, p, n = A.shape
-    X = np.zeros((n, n), dtype=np.float64)
-    for i in range(m):
-        Ai = A[i]
-        G = Ai @ Ai.T                      # (p, p) Gram
-        X += Ai.T @ np.linalg.solve(G, Ai)
-    return X / m
+    with span("repro.spectral.x_matrix"):
+        A = np.asarray(sys.A_blocks, dtype=np.float64)
+        m, p, n = A.shape
+        X = np.zeros((n, n), dtype=np.float64)
+        for i in range(m):
+            Ai = A[i]
+            G = Ai @ Ai.T                      # (p, p) Gram
+            X += Ai.T @ np.linalg.solve(G, Ai)
+        return X / m
 
 
 def mu_extremes(X: np.ndarray) -> tuple[float, float]:
     """(mu_min, mu_max) of X. Eigenvalues lie in [0, 1] (sum of projections)."""
-    w = np.linalg.eigvalsh(X)
+    with span("repro.spectral.eig"):
+        w = np.linalg.eigvalsh(X)
     return float(w[0]), float(w[-1])
 
 

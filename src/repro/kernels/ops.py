@@ -54,6 +54,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.runtime.spans import span
+
 from . import block_projection as bp
 from . import ref
 
@@ -215,7 +217,9 @@ def pick_bn(n_pad: int, p_pad: int = 8, dtype=jnp.float32, *,
     if len(cands) == 1 or not _autotune_enabled(interpret):
         bn = cands[0]
     else:
-        bn = _measure_bn(key[0], key[1], np.dtype(dtype), cands, interpret)
+        with span("repro.ops.autotune", what="bn"):
+            bn = _measure_bn(key[0], key[1], np.dtype(dtype), cands,
+                             interpret)
     _BN_CACHE[key] = bn
     return bn
 
@@ -316,8 +320,9 @@ def pick_tiles(n_pad: int, p_pad: int = 8, k_pad: int = 1,
         default = (default_tiles(bn, key[1], key[0], dtype)
                    or (key[1], key[0]))
         if _autotune_enabled(interpret) and (p_pad > 8 or k_pad > 8):
-            hit = _measure_tiles(key[0], key[1], key[2], np.dtype(dtype),
-                                 bn, default, interpret)
+            with span("repro.ops.autotune", what="tiles"):
+                hit = _measure_tiles(key[0], key[1], key[2],
+                                     np.dtype(dtype), bn, default, interpret)
         else:
             hit = default
         _TILE_CACHE[key] = hit
@@ -546,9 +551,10 @@ def use_fused(family: str, p: int, n: int, k: int = 1,
     if hit is not None:
         return hit
     if _autotune_enabled(interpret):
-        fused = _measure_engine(family, p_pad, n_pad, k_pad,
-                                np.dtype(dtype), interpret,
-                                w=(int(w) if sparse else None))
+        with span("repro.ops.autotune", what="engine"):
+            fused = _measure_engine(family, p_pad, n_pad, k_pad,
+                                    np.dtype(dtype), interpret,
+                                    w=(int(w) if sparse else None))
     else:
         # the measured trend (BENCH_PR5/PR6): the fused engine wins
         # wherever the RHS batch fills the 8-sublane tile or the APC

@@ -19,7 +19,9 @@ all at t=0) and served by the overlapped admission/assembly/execution
 stages (``--pipeline-depth`` in-flight batches, ``--admit-capacity``
 bounds queued+in-flight requests — overflow is shed with an explicit
 result, not queued).  The run ends with the SLO latency report
-(p50/p95/p99) and the shed rate.
+(p50/p95/p99), the shed rate, and where the mean request waited: queued
+for the assembly thread, held (assembly, ``place_B``, a free executor
+slot), and running (behind the batch ahead, its own batch, readback).
 
     PYTHONPATH=src python -m repro.launch.serve_linsys --async \
         --requests 24 --arrival-rate 50 --pipeline-depth 2
@@ -140,6 +142,9 @@ def main(argv=None):
               f"{n_shed} shed over {srv.stats.batches} batches")
         print(f"latency p50/p95/p99 {rep['p50_ms']:.0f}/{rep['p95_ms']:.0f}"
               f"/{rep['p99_ms']:.0f} ms  mean {rep['mean_ms']:.0f} ms")
+        st = srv.stage_means()
+        print(f"mean stages: queue {st['queue_ms']:.1f} ms, hold "
+              f"{st['hold_ms']:.1f} ms, run {st['run_ms']:.1f} ms")
     else:
         for i in range(args.requests):
             srv.submit(fps[picks[i]], rhss[i])

@@ -806,19 +806,21 @@ def _history_scan_many(step_many, extract, factors, Bb, states, A,
 
         states, res = jax.lax.scan(body, states, None, length=iters)
         X = jax.vmap(extract)(states)
-        r = blockops.bmatvec_many(A, X) - Bb
-        final = jnp.sqrt(jnp.sum(r * r, axis=(1, 2))) / b_norms
+        with jax.named_scope("residual"):
+            r = blockops.bmatvec_many(A, X) - Bb
+            final = jnp.sqrt(jnp.sum(r * r, axis=(1, 2))) / b_norms
         res = jnp.concatenate([res[1:], final[None]], axis=0)
         return states, res.T                               # (k, T)
 
     def body(states, _):
         states = step_many(factors, Bb, states)
         X = jax.vmap(extract)(states)                      # (k, n)
-        if residual_fn is None:
-            r = blockops.bmatvec_many(A, X) - Bb
-            res = jnp.sqrt(jnp.sum(r * r, axis=(1, 2))) / b_norms
-        else:
-            res = jax.vmap(residual_fn)(Bb, X)
+        with jax.named_scope("residual"):
+            if residual_fn is None:
+                r = blockops.bmatvec_many(A, X) - Bb
+                res = jnp.sqrt(jnp.sum(r * r, axis=(1, 2))) / b_norms
+            else:
+                res = jax.vmap(residual_fn)(Bb, X)
         return states, res
 
     states, res = jax.lax.scan(body, states, None, length=iters)

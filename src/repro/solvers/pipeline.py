@@ -26,8 +26,13 @@ bounded queues:
      invariant, ``jit_cache_size()`` constant under load); system A's
      solve overlaps system B's assembly and readback.
   4. **Streaming result return** — each request's future resolves to a
-     ``Served`` (or ``Shed``) the moment its batch completes; per-request
-     latency (submit → result) is recorded for the SLO report.
+     ``Served`` (or ``Shed``) the moment its batch completes.
+
+Every served request leaves a stage record (``stages()``): when it was
+submitted, when the assembly thread took its group off the queue, when
+an executor began its batch, and when the batch's answers were on the
+host.  The SLO report (``latency_report()``: submit → result) and the
+three stage means (``stage_means()``: queue, hold, run) read it.
 
 Everything the synchronous lifecycle guarantees composes unchanged:
 ``use_kernel=True`` (fused multi-RHS Pallas kernels), ``warm_start=True``
@@ -43,18 +48,18 @@ batches so state hand-off is exact), and ``backend="mesh"`` through
         for t in tickets:
             r = t.result()                      # Served or Shed
     srv.latency_report()                        # p50/p95/p99 ms, count
+    srv.stage_means()                           # queue/hold/run mean ms
 """
 from __future__ import annotations
 
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
-from typing import Any, List, NamedTuple, Optional, Union
+from typing import Dict, List, NamedTuple, Optional, Tuple, Union
 
 import numpy as np
 
-from .api import iters_to_tolerance
-from .serve import LinsysServer, Served, take_group
+from .serve import LinsysServer, Served, _Work, take_group
 from .store import FactorStore
 
 
@@ -88,17 +93,10 @@ class _AsyncRequest(NamedTuple):
     t_submit: float
 
 
-class _Work(NamedTuple):
-    """One assembled batch handed from the assembly stage to the executor
-    pool (arrays already placed on device by the assembly thread)."""
-    fp: str
-    ent: Any
-    ex: Any
-    group: List[_AsyncRequest]
-    n_real: int
-    Bb: np.ndarray          # host copy (warm-start repeat detection)
-    Bb_dev: Any             # device copy (place_B on the assembly thread)
-    warm: bool
+# a stage record's fields, one row per served request; the clocks are
+# ``time.perf_counter`` seconds
+STAGE_FIELDS = ("rid", "batch", "t_submit", "t_taken", "t_dispatch",
+                "t_done")
 
 
 class AsyncLinsysServer(LinsysServer):
@@ -139,7 +137,8 @@ class AsyncLinsysServer(LinsysServer):
         self._inflight = 0        # batches dispatched and not yet completed
         self._busy = set()        # fps serialized for warm-state chaining
         self._tickets: List[Ticket] = []
-        self._lat: List[float] = []
+        self._stages: List[Tuple] = []       # STAGE_FIELDS per served rid
+        self._bid = 0                        # next batch id
         self._stopping = False
         self._assembler: Optional[threading.Thread] = None
         self._pool: Optional[ThreadPoolExecutor] = None
@@ -271,7 +270,8 @@ class AsyncLinsysServer(LinsysServer):
         group, n_real = take_group(self._queues[fp], self.batch)
         if self.warm_start:
             self._busy.add(fp)
-        return fp, group, n_real
+        bid, self._bid = self._bid, self._bid + 1
+        return fp, group, n_real, bid, time.perf_counter()
 
     def _assemble_loop(self):
         while True:
@@ -282,8 +282,13 @@ class AsyncLinsysServer(LinsysServer):
                         return
                     self._work.wait(0.05)
                     item = self._next_group()
-            fp, group, n_real = item
+            fp, group, n_real, bid, t_taken = item
             try:
+                # the sync step()'s own assembly (one assembly thread, so
+                # the per-system placement cache and the executor cache
+                # need no extra locking); place_B runs on THIS thread, so
+                # the transfer of the next batch double-buffers behind the
+                # executing one
                 work = self._assemble(fp, group, n_real)
             except Exception as e:               # noqa: BLE001 — stage must
                 self._complete_error(fp, group[:n_real], e)   # not die
@@ -293,43 +298,16 @@ class AsyncLinsysServer(LinsysServer):
             self._slots.acquire()
             with self._lock:
                 self._inflight += 1
-            self._pool.submit(self._execute, work)
-
-    def _assemble(self, fp: str, group, n_real: int) -> _Work:
-        """Store lookup, executor acquisition, placement — all identical
-        to the sync ``step()`` (single assembly thread, so the per-system
-        placement cache and the executor cache need no extra locking)."""
-        ent = self._systems[fp]
-        factors = self.store.factors(self.solver, ent.sys, key=fp,
-                                     use_kernel=ent.use_kernel, **ent.prm)
-        ex = self._executor(ent)
-        if ent.placed_src is not factors:        # first batch/post-eviction
-            ent.A_placed, ent.factors_placed = ex.place_system(ent.sys,
-                                                               factors)
-            ent.placed_src = factors
-        Bb = np.stack([r.rhs for r in group]).reshape(
-            len(group), ent.sys.m, ent.sys.p)
-        warm = self._warm_ok(ent, Bb)
-        # host->device on THIS thread: the transfer of the next batch
-        # double-buffers behind the executing one
-        Bb_dev = ex.place_B(Bb)
-        return _Work(fp=fp, ent=ent, ex=ex, group=list(group),
-                     n_real=n_real, Bb=Bb, Bb_dev=Bb_dev, warm=warm)
+            self._pool.submit(self._execute, work, bid, t_taken)
 
     # ----- stage 3+4: execution pool, streaming completion ------------------
-    def _execute(self, w: _Work) -> None:
+    def _execute(self, w: _Work, bid: int, t_taken: float) -> None:
+        t_dispatch = time.perf_counter()
         try:
-            states, X, res = w.ex.run(
-                w.ent.A_placed, w.ent.factors_placed, w.Bb_dev,
-                w.ent.last_states if w.warm else None)
-            X = np.asarray(X)                    # blocks until device done
-            res = np.asarray(res)
-            to_tol = np.atleast_1d(iters_to_tolerance(res, self.tol))
-            t_done = time.perf_counter()
-            out = [Served(rid=r.rid, fp=w.fp, x=X[i],
-                          residual=float(res[i, -1]),
-                          iters_to_tol=int(to_tol[i]), warm=w.warm)
-                   for i, r in enumerate(w.group[:w.n_real])]
+            states, X, res = self._run(w)
+            t_done = time.perf_counter()         # the answers are on the host
+            out = self._served(w, X, res)
+            real = w.group[:w.n_real]
             with self._lock:
                 if self.warm_start:
                     w.ent.last_states, w.ent.last_Bb = states, w.Bb
@@ -338,13 +316,14 @@ class AsyncLinsysServer(LinsysServer):
                 self.stats.served += w.n_real
                 self.stats.padded += len(w.group) - w.n_real
                 self.stats.warm_batches += int(w.warm)
-                for r in w.group[:w.n_real]:
-                    self._lat.append(t_done - r.t_submit)
+                self._stages.extend(
+                    (r.rid, bid, r.t_submit, t_taken, t_dispatch,
+                     t_done) for r in real)
                 self._in_system -= w.n_real
                 self._inflight -= 1
                 self._work.notify_all()
                 self._idle.notify_all()
-            for r, s in zip(w.group[:w.n_real], out):
+            for r, s in zip(real, out):
                 r.future.set_result(s)
         except Exception as e:                   # noqa: BLE001
             with self._lock:
@@ -391,16 +370,41 @@ class AsyncLinsysServer(LinsysServer):
             self.start()
         return [t.future.result() for t in tickets]
 
+    def stages(self) -> Dict[str, np.ndarray]:
+        """The stage record of every request served since the last
+        ``reset_metrics()``, in completion order: one array per
+        ``STAGE_FIELDS`` name.  A request's queue wait is ``t_taken −
+        t_submit``; its hold (assembly, ``place_B``, the wait for an
+        executor slot) ``t_dispatch − t_taken``; its run (behind the batch
+        ahead on the device, its own iterations, the readback) ``t_done −
+        t_dispatch``.  Shed requests have no record."""
+        with self._lock:
+            rows = list(self._stages)
+        cols = list(zip(*rows)) if rows else [()] * len(STAGE_FIELDS)
+        return {name: np.asarray(col, dtype=int if name in ("rid", "batch")
+                                 else float)
+                for name, col in zip(STAGE_FIELDS, cols)}
+
+    def stage_means(self) -> Dict[str, float]:
+        """Mean queue, hold and run milliseconds over the stage record
+        (they add up to the mean latency; NaN before any request)."""
+        st = self.stages()
+        parts = {"queue_ms": st["t_taken"] - st["t_submit"],
+                 "hold_ms": st["t_dispatch"] - st["t_taken"],
+                 "run_ms": st["t_done"] - st["t_dispatch"]}
+        return {k: float(v.mean() * 1e3) if v.size else float("nan")
+                for k, v in parts.items()}
+
     def latencies(self) -> np.ndarray:
         """Per-request submit→result latencies (seconds) so far."""
-        with self._lock:
-            return np.asarray(self._lat, dtype=float)
+        st = self.stages()
+        return st["t_done"] - st["t_submit"]
 
     def reset_metrics(self) -> None:
-        """Clear the latency record and traffic counters (keeps executors,
+        """Clear the stage record and traffic counters (keeps executors,
         placements, and warm states — benchmarks prime then measure)."""
         with self._lock:
-            self._lat = []
+            self._stages = []
             builds = self.stats.executor_builds
             self.stats = type(self.stats)(executor_builds=builds)
 

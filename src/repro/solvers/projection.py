@@ -7,6 +7,7 @@ parameters from the Theorem-1 spectral analysis of X when none are given.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional
 
 import jax
@@ -22,6 +23,20 @@ from repro.core.partition import BlockSystem
 
 from .api import Solver
 from .registry import register
+
+
+def _scoped(name: str):
+    """Trace the decorated function under ``jax.named_scope(name)``: the
+    device trace's op metadata then names the phase (the Cholesky solves
+    and the kernel pair already carry ``jit(_cho_solve)``,
+    ``jit(proj_gather)``, ``jit(block_projection)``... beneath it)."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return deco
 
 
 class ProjFactors(NamedTuple):
@@ -153,11 +168,13 @@ class APCSolver(Solver):
     def kernel_factors(self, factors):
         return _with_pinv(factors)
 
+    @_scoped("apc.init")
     def init(self, factors, b, params):
         x0 = _min_norm_solutions(factors, b)
         return APCState(x=x0, xbar=jnp.mean(x0, axis=0),
                         t=jnp.zeros((), jnp.int32))
 
+    @_scoped("apc.step")
     def step(self, factors, b, state, params, *, use_kernel=False):
         gamma, eta = params["gamma"], params["eta"]
         if blockops.is_sparse(factors.A):
@@ -207,6 +224,7 @@ class APCSolver(Solver):
         return apc_core.apc_step(legacy, state, gamma, eta,
                                  use_kernel=use_kernel)
 
+    @_scoped("apc.step")
     def step_many(self, factors, Bb, states, params, *, use_kernel=False):
         """Fused multi-RHS iteration: the k batch rows stream through ONE
         VMEM residency of every A/B tile (states.x (k, m, n))."""
@@ -298,6 +316,7 @@ class APCSolver(Solver):
             x_new = state.x + gamma * proj
         return x_new, u
 
+    @_scoped("apc.step")
     def step_residual(self, factors, b, state, params):
         gamma, eta = params["gamma"], params["eta"]
         x_new, u = self._step_u(factors, state, gamma)
@@ -306,6 +325,7 @@ class APCSolver(Solver):
         return (APCState(x=x_new, xbar=xbar_new, t=state.t + 1),
                 jnp.sum(u * u))
 
+    @_scoped("apc.step")
     def step_many_residual(self, factors, Bb, states, params):
         gamma, eta = params["gamma"], params["eta"]
         kern = factors.B is not None

@@ -36,8 +36,10 @@ server silently falls back to a cold init for them).
 from __future__ import annotations
 
 import dataclasses
+import threading
 from collections import deque
-from typing import Any, Dict, NamedTuple, Optional, Sequence, Tuple
+from contextlib import contextmanager
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +47,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 
 from repro.core.partition import BlockSystem
+from repro.runtime.spans import span
 
 from .api import LOCAL_PSUM, _history_scan_many, iters_to_tolerance
 from .capability import (ExecutionPlan, check_capability,
@@ -113,6 +116,38 @@ class _System:
     last_Bb: Optional[np.ndarray] = None
 
 
+class _Work(NamedTuple):
+    """One assembled batch: its system, executor and group, with B placed
+    on the device (``Bb`` is the host copy, for warm-start repeats)."""
+    fp: str
+    ent: Any
+    ex: Any
+    group: List[Any]
+    n_real: int
+    Bb: np.ndarray
+    Bb_dev: Any
+    warm: bool
+
+
+@contextmanager
+def _compile_once(lock: threading.Lock, fn):
+    """Around a call of the jitted ``fn``: a call that finds nothing
+    compiled holds ``lock`` and runs under the span
+    ``repro.linsys.compile`` (its trace, where the engine autotune
+    measures, and its compile).  A first call of another thread waits for
+    it and then finds ``fn`` compiled, so an executor's programs are
+    compiled, autotuned and counted once."""
+    if fn._cache_size():
+        yield
+        return
+    with lock:
+        if fn._cache_size():
+            yield
+            return
+        with span("repro.linsys.compile"):
+            yield
+
+
 class _LocalExecutor:
     """Compile-once single-host executor: jitted init+scan over a padded
     (batch, m, p) RHS block.  One instance serves every system that shares
@@ -153,6 +188,7 @@ class _LocalExecutor:
 
         self._cold = jax.jit(_cold)
         self._warm = jax.jit(_run)
+        self._compile_lock = threading.Lock()
 
     def place_system(self, sys: BlockSystem, factors):
         return sys.A_op, factors
@@ -165,8 +201,10 @@ class _LocalExecutor:
 
     def run(self, A, factors, Bb, states=None):
         if states is None:
-            return self._cold(A, factors, Bb)
-        return self._warm(A, factors, Bb, states)
+            with _compile_once(self._compile_lock, self._cold):
+                return self._cold(A, factors, Bb)
+        with _compile_once(self._compile_lock, self._warm):
+            return self._warm(A, factors, Bb, states)
 
     def cache_size(self) -> int:
         return self._cold._cache_size() + self._warm._cache_size()
@@ -189,6 +227,7 @@ class _MeshExecutor:
             a_spec=mesh_backend.operand_specs(sys, self.ctx),
             ls_mode=sys.mode == "least_squares",
             fused_residual=use_kernel)
+        self._compile_lock = threading.Lock()
 
     def place_system(self, sys: BlockSystem, factors):
         from . import mesh as mesh_backend
@@ -204,9 +243,10 @@ class _MeshExecutor:
                               NamedSharding(self.mesh, self.runner.Bb_spec))
 
     def run(self, A, factors, Bb, states=None):
-        if states is None:
-            states = self.runner.init(factors, Bb)
-        return self.runner.run(A, Bb, factors, states)
+        with _compile_once(self._compile_lock, self.runner.run):
+            if states is None:
+                states = self.runner.init(factors, Bb)
+            return self.runner.run(A, Bb, factors, states)
 
     def cache_size(self) -> int:
         return self.runner.cache_size()
@@ -399,9 +439,22 @@ class LinsysServer:
         if not pending:
             return []
         fp = min(pending)[1]
-        ent = self._systems[fp]
         group, n_real = take_group(self._queues[fp], self.batch)
+        w = self._assemble(fp, group, n_real)
+        states, X, res = self._run(w)
+        w.ent.last_states, w.ent.last_Bb = states, w.Bb
 
+        self.stats.batches += 1
+        self.stats.served += n_real
+        self.stats.padded += len(group) - n_real
+        self.stats.warm_batches += int(w.warm)
+        return self._served(w, X, res)
+
+    # ----- the stages of one batch, shared with the async server ------------
+    def _assemble(self, fp: str, group, n_real: int) -> _Work:
+        """Store lookup, executor acquisition, placement of A (first batch
+        or after an eviction) and of the batch's B."""
+        ent = self._systems[fp]
         # every factor acquisition goes through the store (hit after the
         # first batch; key precomputed at register() so no re-hash of A;
         # the kernel path augments the cached entry with the pinv factors
@@ -414,26 +467,27 @@ class LinsysServer:
             ent.A_placed, ent.factors_placed = ex.place_system(ent.sys,
                                                                factors)
             ent.placed_src = factors
-
         Bb = np.stack([r.rhs for r in group]).reshape(
             len(group), ent.sys.m, ent.sys.p)
-        warm = self._warm_ok(ent, Bb)
-        states, X, res = ex.run(ent.A_placed, ent.factors_placed,
-                                ex.place_B(Bb),
-                                ent.last_states if warm else None)
-        ent.last_states, ent.last_Bb = states, Bb
+        return _Work(fp=fp, ent=ent, ex=ex, group=list(group),
+                     n_real=n_real, Bb=Bb, Bb_dev=ex.place_B(Bb),
+                     warm=self._warm_ok(ent, Bb))
 
-        self.stats.batches += 1
-        self.stats.served += n_real
-        self.stats.padded += len(group) - n_real
-        self.stats.warm_batches += int(warm)
-        X = np.asarray(X)
-        res = np.asarray(res)
+    def _run(self, w: _Work):
+        """Dispatch the batch and wait for its answers on the host:
+        (final states, X, residual histories)."""
+        states, X, res = w.ex.run(w.ent.A_placed, w.ent.factors_placed,
+                                  w.Bb_dev,
+                                  w.ent.last_states if w.warm else None)
+        return states, np.asarray(X), np.asarray(res)   # waits for device
+
+    def _served(self, w: _Work, X: np.ndarray, res: np.ndarray):
+        """The ``Served`` results of the batch's real requests."""
         to_tol = np.atleast_1d(iters_to_tolerance(res, self.tol))
-        return [Served(rid=r.rid, fp=fp, x=X[i],
+        return [Served(rid=r.rid, fp=w.fp, x=X[i],
                        residual=float(res[i, -1]),
-                       iters_to_tol=int(to_tol[i]), warm=warm)
-                for i, r in enumerate(group[:n_real])]
+                       iters_to_tol=int(to_tol[i]), warm=w.warm)
+                for i, r in enumerate(w.group[:w.n_real])]
 
     def drain(self):
         """Serve until every queue is empty; results in served order."""

@@ -54,6 +54,7 @@ import numpy as np
 
 from repro.checkpoint.ckpt import COMMIT
 from repro.core.partition import BlockSystem
+from repro.runtime.spans import span
 
 log = logging.getLogger("repro.solvers.store")
 
@@ -77,29 +78,30 @@ def fingerprint(solver_name: str, sys: BlockSystem,
     while ``precision="default"`` adds NOTHING, keeping every existing
     fingerprint byte-stable.
     """
-    A = np.asarray(jax.device_get(sys.A_blocks))
-    h = hashlib.sha256()
-    h.update(f"solver={solver_name}".encode())
-    h.update(f"partition={tuple(A.shape)}".encode())
-    h.update(f"dtype={A.dtype}".encode())
-    for k in sorted(params):
-        try:
-            # normalize numeric types: 1.3, np.float64(1.3) and a jax
-            # scalar must hash identically or cross-call-path lookups
-            # (auto-tuned vs hand-passed params) silently always miss
-            v = repr(float(params[k]))
-        except (TypeError, ValueError):
-            v = repr(params[k])
-        h.update(f"param:{k}={v}".encode())
-    h.update(np.ascontiguousarray(A).tobytes())
-    if sys.is_sparse:
-        cols = np.asarray(jax.device_get(sys.cols))
-        h.update(b"structure=sparse")
-        h.update(f"support={tuple(cols.shape)}".encode())
-        h.update(np.ascontiguousarray(cols).tobytes())
-    if precision != "default":
-        h.update(f"precision={precision}".encode())
-    return h.hexdigest()
+    with span("repro.store.fingerprint"):
+        A = np.asarray(jax.device_get(sys.A_blocks))
+        h = hashlib.sha256()
+        h.update(f"solver={solver_name}".encode())
+        h.update(f"partition={tuple(A.shape)}".encode())
+        h.update(f"dtype={A.dtype}".encode())
+        for k in sorted(params):
+            try:
+                # normalize numeric types: 1.3, np.float64(1.3) and a jax
+                # scalar must hash identically or cross-call-path lookups
+                # (auto-tuned vs hand-passed params) silently always miss
+                v = repr(float(params[k]))
+            except (TypeError, ValueError):
+                v = repr(params[k])
+            h.update(f"param:{k}={v}".encode())
+        h.update(np.ascontiguousarray(A).tobytes())
+        if sys.is_sparse:
+            cols = np.asarray(jax.device_get(sys.cols))
+            h.update(b"structure=sparse")
+            h.update(f"support={tuple(cols.shape)}".encode())
+            h.update(np.ascontiguousarray(cols).tobytes())
+        if precision != "default":
+            h.update(f"precision={precision}".encode())
+        return h.hexdigest()
 
 
 @dataclasses.dataclass
@@ -276,11 +278,17 @@ class FactorStore:
         factors = self.lookup(solver, sys, key=key, use_kernel=use_kernel,
                               precision=precision, **prm)
         if factors is None:
-            factors = self.insert(solver, sys,
-                                  solver.prepare(sys.A_op, prm),
-                                  resume=resume, key=key,
-                                  use_kernel=use_kernel,
-                                  precision=precision, **prm)
+            with span("repro.store.prepare"):
+                factors = self.insert(solver, sys,
+                                      solver.prepare(sys.A_op, prm),
+                                      resume=resume, key=key,
+                                      use_kernel=use_kernel,
+                                      precision=precision, **prm)
+                # wait for the factors here, so that their device time is
+                # charged to the prepare and not to whatever next waits on
+                # the device; a hit never blocks, a miss (set-up, or the
+                # first batch after an eviction) holds its caller
+                jax.block_until_ready(factors)
         return factors
 
     def _augment(self, solver, key: str, factors):

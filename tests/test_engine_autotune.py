@@ -9,6 +9,7 @@ import pytest
 from repro import solvers
 from repro.data import linsys
 from repro.kernels import ops as kops
+from repro.runtime import spans
 from repro.solvers.store import FactorStore
 
 PRM_APC = {"gamma": 1.0, "eta": 1.0}
@@ -76,6 +77,30 @@ def test_measured_autotune_runs_and_caches(monkeypatch):
     # second call is a cache hit (same answer, no re-measurement)
     assert kops.use_fused("cimmino", 16, 128, 1, jnp.float32,
                           interpret=True) is got
+
+
+def test_measurements_run_under_autotune_spans(monkeypatch):
+    """Every measurement opens a ``repro.ops.autotune`` span, the engine
+    comparison around the tile searches its fused candidate runs (so the
+    spans nest and their self time is the time measured); a cache hit
+    opens none."""
+    monkeypatch.setenv(kops.AUTOTUNE_ENV, "1")
+    kops.bn_cache_clear()
+    kops.tile_cache_clear()
+    spans.reset()
+    try:
+        got = kops.use_fused("apc", 16, 256, 16, jnp.float32,
+                             interpret=True)
+        t = spans.totals()["repro.ops.autotune"]
+        assert t.count >= 2 and 0 < t.self_s < t.total_s
+        spans.reset()
+        assert kops.use_fused("apc", 16, 256, 16, jnp.float32,
+                              interpret=True) is got
+        assert "repro.ops.autotune" not in spans.totals()
+    finally:
+        spans.reset()
+        kops.bn_cache_clear()
+        kops.tile_cache_clear()
 
 
 def test_unknown_family_rejected():

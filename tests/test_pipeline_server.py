@@ -2,15 +2,26 @@
 the sync server — grouping, results, warm gating, zero-retrace — while
 adding backpressure (explicit Shed), per-request futures, and the SLO
 latency report."""
+import os
+import re
+import subprocess
+import sys
+import time
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.analysis import tracecheck
 from repro.data import linsys
+from repro.runtime import spans
 from repro.solvers.pipeline import AsyncLinsysServer, Shed
 from repro.solvers.serve import LinsysServer
 from repro.solvers.store import FactorStore
 
+ROOT = Path(__file__).resolve().parents[1]
 PRM = {"gamma": 1.0, "eta": 1.0}     # shared explicit params: one
                                      # executor across same-shape systems
 
@@ -286,3 +297,175 @@ def test_async_use_kernel_matches_sync(sys_a):
     for r, e in zip(out, ref):
         assert np.array_equal(r.x, e.x)
         assert r.residual == e.residual
+
+
+# ---------------------------------------------------------------------------
+# per-request stage clocks
+# ---------------------------------------------------------------------------
+
+
+def test_stage_stamps_are_ordered(sys_a):
+    srv = AsyncLinsysServer(FactorStore(), solver="apc", iters=10, batch=2,
+                            **PRM)
+    fp = srv.register(sys_a)
+    rng = np.random.default_rng(10)
+    with srv:
+        tickets = [srv.submit(fp, rng.standard_normal(48))
+                   for _ in range(5)]
+        for t in tickets:
+            t.result(timeout=60)
+    st = srv.stages()
+    assert sorted(st["rid"].tolist()) == [t.rid for t in tickets]
+    assert np.all(st["t_submit"] <= st["t_taken"])
+    assert np.all(st["t_taken"] <= st["t_dispatch"])
+    assert np.all(st["t_dispatch"] <= st["t_done"])
+    submitted = {t.rid: t.t_submit for t in tickets}
+    assert [submitted[r] for r in st["rid"]] == st["t_submit"].tolist()
+    # one batch id per group of two, shared by its requests' stamps
+    for b in np.unique(st["batch"]):
+        same = st["batch"] == b
+        assert 1 <= same.sum() <= 2
+        for name in ("t_taken", "t_dispatch", "t_done"):
+            assert np.unique(st[name][same]).size == 1
+    srv.reset_metrics()
+    assert all(v.size == 0 for v in srv.stages().values())
+    assert np.isnan(srv.stage_means()["queue_ms"])
+
+
+class _FixedExecutor:
+    """Stand-in executor: every batch takes ``seconds``, answers zeros."""
+
+    def __init__(self, seconds, n, iters):
+        self.seconds, self.n, self.iters = seconds, n, iters
+
+    def place_system(self, sys, factors):
+        return None, None
+
+    def place_B(self, Bb):
+        return Bb
+
+    def run(self, A, factors, Bb, states=None):
+        time.sleep(self.seconds)
+        k = Bb.shape[0]
+        return None, np.zeros((k, self.n)), np.ones((k, self.iters))
+
+    def cache_size(self):
+        return 1
+
+
+def test_stages_add_up_to_the_latency_under_a_fixed_executor(sys_a):
+    seconds = 0.05
+    srv = AsyncLinsysServer(FactorStore(), solver="apc", iters=3, batch=2,
+                            pipeline_depth=1, **PRM)
+    ex = _FixedExecutor(seconds, sys_a.n, 3)
+    srv._executor = lambda ent: ex
+    fp = srv.register(sys_a)
+    rng = np.random.default_rng(11)
+    # the whole backlog queued before the pipeline starts: three groups
+    _drive(srv, [fp], [0] * 6, [rng.standard_normal(48) for _ in range(6)])
+    st = srv.stages()
+    queue = st["t_taken"] - st["t_submit"]
+    hold = st["t_dispatch"] - st["t_taken"]
+    run = st["t_done"] - st["t_dispatch"]
+    lat = srv.latencies()
+    np.testing.assert_allclose(queue + hold + run, lat, rtol=0, atol=1e-12)
+    np.testing.assert_array_equal(lat, st["t_done"] - st["t_submit"])
+    assert np.all(run >= seconds)
+    # one executor slot: a group waits for the batch ahead, in the queue
+    # or in the hold, so every later batch starts after the one before
+    starts = np.unique(st["t_dispatch"])
+    assert starts.size == 3 and np.all(np.diff(starts) >= seconds)
+    means = srv.stage_means()
+    assert sum(means.values()) == pytest.approx(1e3 * lat.mean(), rel=1e-9)
+    assert means["run_ms"] >= 1e3 * seconds
+
+
+def test_latency_report_reads_the_stage_record_without_shed_requests(sys_a):
+    srv = AsyncLinsysServer(FactorStore(), solver="apc", iters=10, batch=2,
+                            admit_capacity=3, **PRM)
+    fp = srv.register(sys_a)
+    rng = np.random.default_rng(12)
+    tickets = [srv.submit(fp, rng.standard_normal(48)) for _ in range(5)]
+    srv.drain()
+    srv.close()
+    st = srv.stages()
+    assert sorted(st["rid"].tolist()) == [0, 1, 2]      # the shed are absent
+    rep = srv.latency_report()
+    lat = st["t_done"] - st["t_submit"]
+    assert rep["count"] == 3 == srv.stats.served
+    assert rep["mean_ms"] == pytest.approx(1e3 * lat.mean(), rel=1e-12)
+    assert rep["max_ms"] == pytest.approx(1e3 * lat.max(), rel=1e-12)
+    assert rep["p50_ms"] == pytest.approx(
+        1e3 * np.percentile(lat, 50), rel=1e-12)
+    assert all(isinstance(t.result(), Shed) for t in tickets[3:])
+
+
+def test_mixed_precision_async_caches_and_answers_as_sync(sys_a):
+    """``precision="mixed"`` reaches the async server's store miss: both
+    servers cache the same cast entry and give the same answers."""
+    rng = np.random.default_rng(13)
+    rhs = [rng.standard_normal(48) for _ in range(4)]
+    kw = dict(solver="apc", iters=30, batch=2, use_kernel=True,
+              precision="mixed", **PRM)
+
+    sync_store = FactorStore()
+    sync = LinsysServer(sync_store, **kw)
+    fp = sync.register(sys_a)
+    for b in rhs:
+        sync.submit(fp, b)
+    ref = sync.drain()
+
+    async_store = FactorStore()
+    asrv = AsyncLinsysServer(async_store, **kw)
+    afp = asrv.register(sys_a)
+    _, out = _drive(asrv, [afp] * 4, [0] * 4, rhs)
+
+    assert afp == fp
+    cached = {name: store._mem[fp] for name, store in
+              (("sync", sync_store), ("async", async_store))}
+    for f in cached.values():
+        assert f.A.dtype == jnp.bfloat16 and f.B.dtype == jnp.bfloat16
+    for a, b in zip(jax.tree_util.tree_leaves(cached["sync"]),
+                    jax.tree_util.tree_leaves(cached["async"])):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float64),
+                                      np.asarray(b, np.float64))
+    for r, e in zip(out, ref):
+        assert np.array_equal(r.x, e.x)
+        assert r.residual == e.residual
+
+
+def test_serve_cli_prints_the_stage_means():
+    env = {**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"}
+    out = subprocess.run(
+        [sys.executable, "-m", "repro.launch.serve_linsys", "--async",
+         "--requests", "6", "--systems", "1", "--batch", "2", "--iters",
+         "20", "--n", "32", "--workers", "2", "--tol", "1e-1"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    line = next(ln for ln in out.stdout.splitlines()
+                if ln.startswith("mean stages:"))
+    ms = [float(v) for v in re.findall(r"(\d+\.\d+) ms", line)]
+    assert len(ms) == 3 and all(v >= 0 for v in ms)
+
+
+def test_concurrent_first_batches_compile_once(sys_a):
+    """The benchmark's warm-up pattern: two groups queued at once, two
+    executor slots, so both executor threads make a first call of the
+    same cold executor together.  It is traced and compiled once, under
+    one ``repro.linsys.compile`` span; the other call waits for it."""
+    srv = AsyncLinsysServer(FactorStore(), solver="apc", iters=10, batch=2,
+                            pipeline_depth=2, **PRM)
+    fp = srv.register(sys_a)
+    rng = np.random.default_rng(14)
+    spans.reset()
+    _, out = _drive(srv, [fp], [0] * 4,
+                    [rng.standard_normal(48) for _ in range(4)])
+    assert len(out) == 4 and srv.stats.batches == 2
+    st = srv.stages()
+    # both batches were dispatched before either was done: the two first
+    # calls overlapped
+    assert np.unique(st["t_dispatch"]).size == 2
+    assert st["t_dispatch"].max() < st["t_done"].min()
+    assert spans.totals()["repro.linsys.compile"].count == 1
+    assert srv._executors[srv._systems[fp].executor_key].cache_size() == 1
