@@ -29,11 +29,11 @@ ALLOW: dict[str, tuple[tuple[str, str, str], ...]] = {
          "param) key, constructed once in __init__ and cached by "
          "LinsysServer._executor — this IS the sanctioned home R001 "
          "points at"),
-        ("src/repro/kernels/ops.py", "_measure_engine",
+        ("src/repro/kernels/ops.py", "_engine_candidates",
          "engine autotune measurement: candidate jits are constructed "
-         "once per (family, p, n, k, dtype) probe, timed, then "
-         "discarded; the winning engine is served by the module-scope "
-         "jitted ops"),
+         "once per (family, p, n, k, dtype) probe, timed by "
+         "_measure_engine, then discarded; the winning engine is served "
+         "by the module-scope jitted ops"),
         ("src/repro/core/distributed.py", "*",
          "deprecated shim layer: builds its compiled step once per "
          "DistributedSolve construction (kept for API compat)"),

@@ -48,7 +48,7 @@ import functools
 import logging
 import os
 import time
-from typing import Optional
+from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -345,6 +345,13 @@ def pick_tiles(n_pad: int, p_pad: int = 8, k_pad: int = 1,
 # unfused step when fused loses, so ``use_kernel=True`` always means "the
 # faster engine", never "the fused engine even where it regresses".
 #
+# What "unfused" runs depends on the family.  For dense ``apc`` (APC and
+# consensus) it is the XLA pinv step: the factors of ``use_kernel=True``
+# carry B = Aᵀ G⁻¹, so the fallback contracts against B (one pass over A,
+# one over B) and solves nothing.  For ``cimmino`` and the ``*_sparse``
+# families it is the Cholesky step, two triangular solves per worker.  The
+# measurement times the same step the dispatch runs.
+#
 # ``REPRO_KERNEL_ENGINE=fused|unfused`` pins the choice (benchmarks use it
 # to measure the raw fused path); where measurement is off (interpret mode
 # without REPRO_KERNEL_AUTOTUNE=1) the decision comes from the measured
@@ -379,19 +386,17 @@ _MEAS_WORKERS = 2   # dummy worker axis the engine measurement vmaps over
 _ENGINE_MARGIN = 0.85
 
 
-def _measure_engine(family: str, p_pad: int, n_pad: int, k_pad: int,
-                    dtype, interpret: bool, w: Optional[int] = None) -> bool:
-    """Time the fused kernel pair against the unfused XLA step for the
-    SAME (p, n, k) shape, run the way the solvers actually dispatch
-    them: jitted and ``vmap``-ed over a small dummy worker axis
-    (``_MEAS_WORKERS``).  The per-step dispatch IS ``vmap(worker)`` over
-    the m blocks, and batching a pallas_call — above all through the
-    interpreter — costs far more than batching the equivalent XLA step,
-    so a lone un-vmapped call flatters the fused engine and mis-routes
-    the verdict.  Faster engine wins.  Dummy operands, best-of-3 after
-    a compile warmup (same protocol as ``_measure_bn``).  Sparse
-    families measure the compressed-support fused op against the
-    unfused SparseBlocks-style step on a random w-column support."""
+def _engine_candidates(family: str, p_pad: int, n_pad: int, k_pad: int,
+                       dtype, interpret: bool, w: Optional[int] = None
+                       ) -> Dict[str, Callable[[], Any]]:
+    """The two engines ``_measure_engine`` times for one (p, n, k) shape,
+    as zero-argument calls on dummy operands: ``fused`` (the kernel pair)
+    and ``unfused`` (the XLA step the dispatch falls back to — the pinv
+    step for dense ``apc``, the Cholesky step otherwise).  Each is jitted
+    and ``vmap``-ed over a small dummy worker axis (``_MEAS_WORKERS``), the
+    way the solvers dispatch them.  Sparse families run the
+    compressed-support fused op and the unfused SparseBlocks-style step
+    on a random w-column support."""
     rng = np.random.default_rng(0)
     mw = _MEAS_WORKERS
     if family.endswith("_sparse"):
@@ -479,20 +484,33 @@ def _measure_engine(family: str, p_pad: int, n_pad: int, k_pad: int,
             def fused():
                 return fused_v(A, Bm)
 
-            def _unf(Ai, Li):
+            def _unf(Ai, Bi):
                 d = xbar - x
-                w_ = jax.scipy.linalg.cho_solve((Li, True), (d @ Ai.T).T).T
-                return x + (d - w_ @ Ai)
+                return x + (d - (d @ Ai.T) @ Bi.T)
             unfused_v = jax.jit(jax.vmap(_unf))
 
             def unfused():
-                return unfused_v(A, L)
+                return unfused_v(A, Bm)
+    return {"fused": fused, "unfused": unfused}
 
+
+def _measure_engine(family: str, p_pad: int, n_pad: int, k_pad: int,
+                    dtype, interpret: bool, w: Optional[int] = None) -> bool:
+    """Time the fused kernel pair against the unfused XLA step for the
+    SAME (p, n, k) shape (``_engine_candidates``).  Both run vmapped over
+    a dummy worker axis: the per-step dispatch IS ``vmap(worker)`` over
+    the m blocks, and batching a pallas_call — above all through the
+    interpreter — costs far more than batching the equivalent XLA step,
+    so a lone un-vmapped call flatters the fused engine and mis-routes
+    the verdict.  Faster engine wins.  Best-of-5 after a compile warmup
+    (same protocol as ``_measure_bn``)."""
+    runs = _engine_candidates(family, p_pad, n_pad, k_pad, dtype, interpret,
+                              w=w)
     # true best-of-5: min over separately timed runs, so one scheduler
     # hiccup inside a candidate's window cannot flip the verdict (a
     # summed window did exactly that on loaded single-core CI hosts)
     times = {}
-    for name, run in (("fused", fused), ("unfused", unfused)):
+    for name, run in runs.items():
         jax.block_until_ready(run())             # compile + warm
         best = float("inf")
         for _ in range(5):

@@ -1,9 +1,21 @@
 """Projection-family solvers: APC, plain projection consensus, block Cimmino.
 
-All three share the per-worker null-space projection machinery of
-``core/apc.py`` (Gram Cholesky factors, P_i v = v - A^T G^{-1} A v), support
-the Pallas kernel path uniformly (``use_kernel=True``), and auto-tune their
+All three share the per-worker null-space projection P_i v = v - B_i A_i v
+with B_i = A_i^T G_i^{-1}, G_i = A_i A_i^T the block's Gram, support the
+Pallas kernel path uniformly (``use_kernel=True``), and auto-tune their
 parameters from the Theorem-1 spectral analysis of X when none are given.
+
+Three engines apply the projection:
+
+- the Cholesky step (``core/apc.py``): two triangular solves against the
+  Gram's Cholesky factor per worker per iteration.  Runs under
+  ``use_kernel=False``, where the factors carry no pinv factor B, and
+  where the Pallas pair loses for Cimmino or a sparse operand;
+- the pinv step: under ``use_kernel=True`` the factors carry the dense B
+  (``kernel_factors``), and where the engine autotune (``kops.use_fused``)
+  says the Pallas pair loses, APC and consensus contract against B in
+  plain XLA, one pass over A and one over B with no triangular solve;
+- the Pallas pair (``kernels/``), where the autotune says it wins.
 """
 from __future__ import annotations
 
@@ -45,8 +57,11 @@ class ProjFactors(NamedTuple):
     chol: jnp.ndarray   # (m, p, p) Cholesky of Gram A_i A_i^T
     B: Optional[jnp.ndarray] = None  # pinv factors A^T G^{-1}: (m, n, p)
                                      # dense, (m, w, p) support-compressed
-                                     # for SparseBlocks operands (kernel
-                                     # path only, see kernel_factors)
+                                     # for SparseBlocks operands; present
+                                     # under use_kernel=True only (see
+                                     # kernel_factors), where the Pallas
+                                     # pair and, dense, the XLA pinv step
+                                     # of APC/consensus read it
 
 
 def _proj_prepare(A, jitter: float) -> ProjFactors:
@@ -84,12 +99,30 @@ def _with_pinv(factors: ProjFactors) -> ProjFactors:
 
 
 def _min_norm_solutions(factors: ProjFactors, b: jnp.ndarray) -> jnp.ndarray:
-    """x0_i = A_i^T (A_i A_i^T)^{-1} b_i — the min-norm local solutions."""
+    """x0_i = A_i^T (A_i A_i^T)^{-1} b_i — the min-norm local solutions;
+    B_i b_i where the dense pinv factor is present."""
     if blockops.is_sparse(factors.A):
         return blockops.brmatvec(factors.A,
                                  _cho_solve_workers(factors.chol, b))
+    if factors.B is not None:
+        with jax.named_scope("apc.pinv"):
+            return jnp.einsum("mnp,mp->mn", factors.B, b)
     return jax.vmap(lambda Ai, Li, bi: Ai.T @ _gram_solve(Li, bi))(
         factors.A, factors.chol, b)
+
+
+def _pinv_update(factors: ProjFactors, x, xbar, gamma):
+    """Eq. 2a against the stored dense pinv factor: (x_new, u) with
+    u_i = A_i d_i, d_i = x̄ − x_i (the gather pass, and the residual source)
+    and x_new_i = x_i + γ (d_i − B_i u_i).  B_i u_i = A_i^T G_i^{-1} u_i is
+    the Cholesky step's two triangular solves and second pass over A in one
+    contraction over B's minor axis.  Batch-polymorphic: x (m, n) with
+    x̄ (n,), or x (k, m, n) with x̄ (k, n)."""
+    d = xbar[..., None, :] - x
+    u = jnp.einsum("mpn,...mn->...mp", factors.A, d)
+    with jax.named_scope("apc.pinv"):
+        x_new = x + gamma * (d - jnp.einsum("mnp,...mp->...mn", factors.B, u))
+    return x_new, u
 
 
 def _cho_solve_workers(chol, u):
@@ -166,6 +199,10 @@ class APCSolver(Solver):
         return _proj_prepare(A, params.get("jitter", 0.0))
 
     def kernel_factors(self, factors):
+        """Add the pinv factors B (``_with_pinv``).  Dense B serves both
+        engines of ``use_kernel=True``: the Pallas pair, and the XLA pinv
+        step (``_pinv_update``, and the init's B_i b_i) where the engine
+        autotune says the pair loses."""
         return _with_pinv(factors)
 
     @_scoped("apc.init")
@@ -206,8 +243,8 @@ class APCSolver(Solver):
             from repro.kernels import ops as kops
             # the engine autotune includes "unfused" as a candidate: when
             # the fused pair loses at this (p, n, k=1, dtype) the step
-            # falls through to the plain XLA path below (trace-time
-            # choice — baked into the compiled executor, never retraced)
+            # runs the XLA pinv step instead (trace-time choice — baked
+            # into the compiled executor, never retraced)
             if kops.use_fused("apc", factors.A.shape[1], factors.A.shape[2],
                               1, factors.A.dtype):
                 def worker(Ai, Bi, xi):
@@ -215,10 +252,11 @@ class APCSolver(Solver):
                                                  gamma)
 
                 x_new = jax.vmap(worker)(factors.A, factors.B, state.x)
-                xbar_new = (eta * jnp.mean(x_new, axis=0)
-                            + (1.0 - eta) * state.xbar)
-                return APCState(x=x_new, xbar=xbar_new, t=state.t + 1)
-            use_kernel = False                   # measured fallback
+            else:
+                x_new, _ = _pinv_update(factors, state.x, state.xbar, gamma)
+            xbar_new = (eta * jnp.mean(x_new, axis=0)
+                        + (1.0 - eta) * state.xbar)
+            return APCState(x=x_new, xbar=xbar_new, t=state.t + 1)
         legacy = apc_core.APCFactors(A=factors.A, chol=factors.chol,
                                      x0=None, b=None)
         return apc_core.apc_step(legacy, state, gamma, eta,
@@ -249,17 +287,17 @@ class APCSolver(Solver):
             xbar_new = (eta * jnp.mean(x_new, axis=1)
                         + (1.0 - eta) * states.xbar)
             return APCState(x=x_new, xbar=xbar_new, t=states.t + 1)
-        if not kops.use_fused("apc", factors.A.shape[1], factors.A.shape[2],
-                              Bb.shape[0], factors.A.dtype):
-            return super().step_many(factors, Bb, states, params,
-                                     use_kernel=False)   # measured fallback
-        X = jnp.swapaxes(states.x, 0, 1)                  # (m, k, n)
+        if kops.use_fused("apc", factors.A.shape[1], factors.A.shape[2],
+                          Bb.shape[0], factors.A.dtype):
+            X = jnp.swapaxes(states.x, 0, 1)              # (m, k, n)
 
-        def worker(Ai, Bi, Xi):
-            return kops.block_projection(Ai, Bi, Xi, states.xbar, gamma)
+            def worker(Ai, Bi, Xi):
+                return kops.block_projection(Ai, Bi, Xi, states.xbar, gamma)
 
-        x_new = jnp.swapaxes(
-            jax.vmap(worker)(factors.A, factors.B, X), 0, 1)   # (k, m, n)
+            x_new = jnp.swapaxes(
+                jax.vmap(worker)(factors.A, factors.B, X), 0, 1)  # (k, m, n)
+        else:                                     # measured fallback
+            x_new, _ = _pinv_update(factors, states.x, states.xbar, gamma)
         xbar_new = (eta * jnp.mean(x_new, axis=1)
                     + (1.0 - eta) * states.xbar)
         return APCState(x=x_new, xbar=xbar_new, t=states.t + 1)
@@ -308,6 +346,8 @@ class APCSolver(Solver):
                 lambda Bi, xi, ui: kops.proj_scatter(Bi, xi, state.xbar,
                                                      ui, gamma))(
                     factors.B, state.x, u)
+        elif factors.B is not None and not sparse:
+            x_new, u = _pinv_update(factors, state.x, state.xbar, gamma)
         else:
             d = state.xbar[None, :] - state.x
             u = blockops.bmatvec_each(factors.A, d)
@@ -361,6 +401,9 @@ class APCSolver(Solver):
                             factors.B, X, u)              # (m, k, n)
             x_new = jnp.swapaxes(x_new, 0, 1)             # (k, m, n)
             rsq = jnp.sum(u * u, axis=(0, 2))             # (k,)
+        elif factors.B is not None and not sparse:
+            x_new, u = _pinv_update(factors, states.x, states.xbar, gamma)
+            rsq = jnp.sum(u * u, axis=(1, 2))             # (k,)
         else:
             def one(xk, xbark):
                 d = xbark[None, :] - xk

@@ -2,6 +2,7 @@
 (family, p, n, k, dtype) — env pin > cache > measurement > heuristic —
 and the projection-family dispatch honors it bit-exactly at trace time
 (the BENCH_PR5 cimmino batch-1 regression, fixed by falling back)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -101,6 +102,29 @@ def test_measurements_run_under_autotune_spans(monkeypatch):
         spans.reset()
         kops.bn_cache_clear()
         kops.tile_cache_clear()
+
+
+@pytest.mark.parametrize("family,solves", [("apc", False),
+                                           ("cimmino", True)])
+def test_measured_unfused_candidate_is_the_dispatched_step(monkeypatch,
+                                                           family, solves):
+    """The ``unfused`` candidate the measurement times is the step the
+    dispatch falls back to: for dense ``apc`` the pinv step, which solves
+    nothing; for ``cimmino`` the Cholesky step, two triangular solves."""
+    monkeypatch.setenv(kops.AUTOTUNE_ENV, "1")
+    built = []
+    candidates = kops._engine_candidates
+
+    def recording(*args, **kwargs):
+        built.append(candidates(*args, **kwargs))
+        return built[-1]
+
+    monkeypatch.setattr(kops, "_engine_candidates", recording)
+    kops.use_fused(family, 16, 128, 1, jnp.float32, interpret=True)
+    assert len(built) == 1
+    jaxpr = str(jax.make_jaxpr(built[0]["unfused"])())
+    assert ("triangular_solve" in jaxpr) is solves
+    assert "dot_general" in jaxpr
 
 
 def test_unknown_family_rejected():
