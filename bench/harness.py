@@ -4,7 +4,9 @@ the seed, set up, measure, check the answers, and assemble the result.
 Everything that belongs to one configuration, traffic mix, cell or metric
 sits in a file of its own that is found by the name in ``BENCHMARK.json``:
 
-    bench/configs/<config>.json        sizes, tolerances, limit, generator
+    bench/configs/<config>.json        sizes, tolerances, limit, generator,
+                                       and ``tiny``: the keys the CPU tests
+                                       override (never read by a run)
     bench/configs/<generator>.py       build(cfg, seed) and the reference
     bench/traffic/<mix>.json           the mix's parameters, its ``loop``
     bench/traffic/<loop>.py            Loop, the driver it names (bench/load.py)
@@ -12,8 +14,10 @@ sits in a file of its own that is found by the name in ``BENCHMARK.json``:
     bench/metrics/<metric>.py          read(run) -> number or None
 
 A later cell, mix, configuration or metric is a new file and a new entry
-in ``BENCHMARK.json``; no file here changes.  ``Files`` searches a list
-of directories in order, so a test can put its own files first.
+in ``BENCHMARK.json``; no file here changes, nor under ``tests/bench``,
+whose tests read every expectation from ``BENCHMARK.json`` and each
+configuration's file.  ``Files`` searches a list of directories in order,
+so a test can put its own files first.
 """
 from __future__ import annotations
 
@@ -162,16 +166,20 @@ class Run:
         return self.stats["batches"] * self.iters
 
     def least_seconds(self) -> Optional[float]:
-        """Least time of the window's iterations on this chip, the real
-        right-hand sides spread evenly over the batches (which, max being
-        convex, never overstates the least time)."""
+        """Least time of the window's iterations on the cell's chips, the
+        real right-hand sides spread evenly over the batches (which, max
+        being convex, never overstates the least time).  The work is shared
+        by the chips that ran ops, so it is divided by their number, as
+        ``busy_s`` is their mean."""
         from bench import work
         if self.peaks is None or not self.stats["batches"]:
             return None
         k = self.stats["served"] / self.stats["batches"]
         p = self.problem
         w = work.apc_iteration(m=p.m, p=p.p, n=p.n, width=p.width, k=k)
-        return work.least_time(w, self.peaks).seconds * self.iterations()
+        chips = self.trace.chips if self.trace is not None else 1
+        return (work.least_time(w, self.peaks).seconds * self.iterations()
+                / chips)
 
     # ----- the reductions the metric readers share -------------------------
     def idle_share(self) -> Optional[float]:

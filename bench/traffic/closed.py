@@ -4,18 +4,40 @@ the next right-hand side is built from the last answer, as an implicit
 time stepper builds it: ``A x`` scaled to ‖b₀‖ plus a seeded forcing term
 ``A g`` of the same norm, so each request waits on the one before.
 
+The record's ``latency_s`` holds one latency per served step: from just
+before ``srv.submit`` to the answer in hand after ``srv.step()`` (a host
+array).  The caller's own ``next_rhs`` work between steps is left out.
+
 Parameters: ``batch``, ``next_rhs``.
 """
 from __future__ import annotations
 
 import time
+from contextlib import contextmanager
 
+import jax
 import numpy as np
 from jax.profiler import TraceAnnotation
 
 from bench import numerics
 from bench.load import FORCING_STREAM, RHS_STREAM, WARM_STREAM, Traffic
 from repro import solvers
+
+
+@contextmanager
+def _persistent_cache_off():
+    """JAX's persistent compilation cache neither read nor written inside;
+    JAX decides once whether it uses the cache, so its decision is reset
+    on entry and on exit."""
+    from jax._src import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
 
 
 class Loop(Traffic):
@@ -32,13 +54,24 @@ class Loop(Traffic):
         return solvers.LinsysServer(**self._server_kw())
 
     def _warm(self) -> None:
+        self._warm_step()
+        # The executor closes over the seed's γ and η: a new seed compiles
+        # it, a seed run before in this cache loads it, and a process that
+        # compiled no program serves each step about 4 ms slower (PERF.md).
+        # So every run compiles the window's programs: drop the in-memory
+        # ones and warm again with the persistent cache off.
+        jax.clear_caches()
+        with _persistent_cache_off():
+            self._warm_step()
+        self.server.stats = type(self.server.stats)(
+            executor_builds=self.server.stats.executor_builds)
+
+    def _warm_step(self) -> None:
         self.server.submit(self.fp, self.warm_b)
         out = self.server.step()
         # the next right-hand side may run on the device: compile it too,
         # with a generator of its own so the window's forcing is the seed's
         self.next_rhs(out[0].x, numerics.host_rng(self.seed, WARM_STREAM))
-        self.server.stats = type(self.server.stats)(
-            executor_builds=self.server.stats.executor_builds)
 
     def next_rhs(self, x: np.ndarray, rng=None) -> np.ndarray:
         """``A x`` of the last answer scaled to ‖b₀‖, plus ``A g`` of a seeded
@@ -50,11 +83,12 @@ class Loop(Traffic):
 
     def window(self) -> dict:
         srv = self.server
-        X, B, to_tol, status = [], [], [], []
+        X, B, to_tol, status, lat = [], [], [], [], []
         b = self.b0
         t0 = time.perf_counter()
         with TraceAnnotation("bench.window"):
             while True:
+                t_sub = time.perf_counter()
                 with TraceAnnotation("bench.submit"):
                     srv.submit(self.fp, b)
                 with TraceAnnotation("bench.step"):
@@ -62,6 +96,7 @@ class Loop(Traffic):
                 if len(out) != 1:
                     status.append("error")
                     break
+                lat.append(time.perf_counter() - t_sub)
                 status.append("served")
                 X.append(out[0].x)
                 B.append(b)
@@ -72,7 +107,8 @@ class Loop(Traffic):
                     b = self.next_rhs(out[0].x)
         t_end = time.perf_counter()
         return {"attempted": len(status),
-                "status": status, "window_s": t_end - t0,
+                "status": status, "latency_s": np.asarray(lat),
+                "window_s": t_end - t0,
                 "steps": len(X), "stats": self.counters(srv.stats),
                 "iters_to_tol": to_tol, "X": X, "B": B}
 

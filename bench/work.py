@@ -17,6 +17,9 @@ place of B) is not in it.
                                             read and written)
 
 with k the real right-hand sides only (padding slots do no useful work).
+The least time is for the cell's chips: where several share the work,
+the one chip's least time is divided by their number
+(``harness.Run.least_seconds``).
 """
 from __future__ import annotations
 

@@ -8,42 +8,185 @@ import json
 
 import pytest
 
-from bench_testkit import CELLS, SECONDS, SPEC, f32, tiny_files  # noqa: F401
-from bench import harness
+from bench_testkit import (CELLS, CONFIGS, SECONDS, SPEC,  # noqa: F401
+                           TINY_OPERAND_BYTES, check_control,
+                           check_end_to_end, check_traced, f32,
+                           operand_bytes, tiny_cfg, tiny_files)
+from bench import harness, trace, work
 from repro.solvers.pipeline import AsyncLinsysServer
-
-E2E = {"tall16k.open": "lat_p95_ms"}
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_runs_end_to_end_and_is_correct(cell, f32, tmp_path):  # noqa: F811
-    r = harness.run_cell(cell, 2**31 + 99, SECONDS, False,
-                         files=tiny_files(tmp_path), require_tpu=False)
-    assert r["correct"] is True
-    assert r["failed"] == 0 and r["attempted"] > 0
-    assert set(r["metrics"]) == {"setup_s", E2E[cell]}
-    assert all(m["value"] > 0 for m in r["metrics"].values())
-    assert r["compiles_in_window"] == 0
-    assert list(r)[-1] == "compared"
-    res = r["compared"]["max_rel_residual"]
-    assert res["value"] <= res["limit"]
-    assert r["device"]["platform"] == "cpu"
+    check_end_to_end(cell, tiny_files(tmp_path))
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_traced_run_reports_per_layer_metrics_only(cell, f32,  # noqa: F811
                                                   tmp_path):
-    r = harness.run_cell(cell, 3, SECONDS, True, files=tiny_files(tmp_path),
-                         require_tpu=False)
-    assert r["correct"] is True
-    names = set(r["metrics"])
-    assert {"analyze_s", "warm_s", f"iters_to_tol.{cell.split('.')[1]}"} \
-        <= names
-    assert "setup_s" not in names and E2E[cell] not in names
-    # the CPU trace holds no TPU plane: the device metrics read nothing
-    assert not any(n.startswith(("idle_share", "iter_us", "iter_roofline"))
-                   for n in names)
-    assert "busy_s" not in r["device"]
+    check_traced(cell, tiny_files(tmp_path))
+
+
+@pytest.mark.parametrize("config", CONFIGS)
+def test_tiny_build_is_small(config, f32):  # noqa: F811
+    """Whatever a configuration's chip size, its CPU tests run at a size
+    that keeps the suite's time bounded."""
+    assert operand_bytes(config) <= TINY_OPERAND_BYTES
+
+
+def test_a_configuration_without_tiny_is_refused_by_name(tmp_path):
+    cfg = harness.Files().json("configs", "tall_gauss_16k")
+    del cfg["tiny"]
+    (tmp_path / "src" / "configs").mkdir(parents=True)
+    (tmp_path / "src" / "configs" / "no_tiny.json").write_text(
+        json.dumps({**cfg, "name": "no_tiny"}))
+    spec = {"configs": [{"name": "no_tiny"}]}
+    files = harness.Files([tmp_path / "src", harness.BENCH_DIR])
+    with pytest.raises(KeyError, match="no_tiny"):
+        tiny_files(tmp_path / "tiny", spec, files)
+
+
+def test_a_configuration_and_its_cell_are_added_by_files_alone(
+        tmp_path, f32):  # noqa: F811
+    """A new configuration with its own ``tiny``, and a cell on it, written
+    as files and spec entries only, pass the checks every cell passes."""
+    base = harness.Files().json("configs", "tall_gauss_16k")
+    config, cell = "tall_gauss_copy", "copy.stream"
+    src = tmp_path / "src"
+    (src / "configs").mkdir(parents=True)
+    (src / "cells").mkdir()
+    (src / "configs" / f"{config}.json").write_text(json.dumps(
+        {**base, "name": config,
+         "tiny": {"N": 192, "n": 96, "m": 3, "iters": 30}}))
+    (src / "cells" / f"{cell}.json").write_text(json.dumps(
+        {"config": config, "traffic": "closed_stream"}))
+    spec = copy.deepcopy(SPEC)
+    spec["configs"].append({**spec["configs"][0], "name": config,
+                            "file": f"bench/configs/{config}.json"})
+    spec["workloads"].append({"name": cell, "config": config,
+                              "traffic": "closed_stream", "chips": 1,
+                              "why": "test"})
+    next(m for m in spec["end_to_end"]
+         if m["name"] == "lat_p95_ms")["workloads"].append(cell)
+    next(m for m in spec["per_layer"]
+         if m["name"] == "iters_to_tol.stream")["workloads"].append(cell)
+    files = harness.Files([src, harness.BENCH_DIR])
+    assert operand_bytes(config, files) <= TINY_OPERAND_BYTES
+    tiny = tiny_files(tmp_path / "tiny", spec, files)
+    r = check_end_to_end(cell, tiny, spec)
+    assert set(r["metrics"]) == {"setup_s", "lat_p95_ms"}
+    r = check_traced(cell, tiny, spec)
+    assert set(r["metrics"]) == {"iters_to_tol.stream"}
+    check_control(cell, tiny, spec)
+
+
+def test_a_closed_loop_records_one_latency_per_served_step(
+        tmp_path, f32):  # noqa: F811
+    """The closed loop's record holds a latency for every step it served,
+    each within the window, so ``lat_p95_ms`` reads in its cells."""
+    from bench import load
+    files = tiny_files(tmp_path)
+    c = harness.resolve("tall16k.stream", SPEC, files)
+    traffic = load.make_traffic(files, c.build(2**31 + 17), c.cfg, c.mix,
+                                2**31 + 17, SECONDS)
+    traffic.setup()
+    try:
+        out = traffic.window()
+    finally:
+        traffic.close()
+    served = sum(s == "served" for s in out["status"])
+    assert served == out["steps"] > 1
+    lat = out["latency_s"]
+    assert len(lat) == served
+    assert (lat > 0).all() and lat.sum() <= out["window_s"]
+
+
+CLOSED_CELLS = [c for c in CELLS
+                if harness.resolve(c, SPEC).mix["loop"] == "closed"]
+
+
+@pytest.fixture
+def fresh_compile_cache(tmp_path):
+    """JAX's persistent compile cache in an empty directory, every program
+    kept, for the duration of a test."""
+    import jax
+    from jax._src import compilation_cache, config
+    keep = {"jax_compilation_cache_dir": config.compilation_cache_dir.value,
+            "jax_persistent_cache_min_compile_time_secs":
+                config.persistent_cache_min_compile_time_secs.value,
+            "jax_persistent_cache_min_entry_size_bytes":
+                config.persistent_cache_min_entry_size_bytes.value}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        for k, v in keep.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("cell", CLOSED_CELLS)
+def test_a_closed_loop_compiles_its_window_programs_in_every_run(
+        cell, tmp_path, f32, fresh_compile_cache):  # noqa: F811
+    """The closed loop's warm-up compiles the executor in this process even
+    where the persistent cache holds it from an earlier run of the seed,
+    so a seed's second run in a checkout serves as its first does."""
+    import jax
+    from bench import load
+    events = {"compiles": 0, "hits": 0}
+
+    def on_duration(name, _secs, **_):
+        if name == "/jax/core/compile/backend_compile_duration":
+            events["compiles"] += 1
+
+    def on_event(name, **_):
+        if name == "/jax/compilation_cache/cache_hits":
+            events["hits"] += 1
+
+    files = tiny_files(tmp_path)
+    c = harness.resolve(cell, SPEC, files)
+    seed = 2**31 + 19
+    fresh = []
+    for _ in range(2):
+        traffic = load.make_traffic(files, c.build(seed), c.cfg, c.mix, seed,
+                                    SECONDS)
+        events.update(compiles=0, hits=0)
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        jax.monitoring.register_event_listener(on_event)
+        try:
+            traffic.setup()
+        finally:
+            jax.monitoring.unregister_event_duration_listener(on_duration)
+            jax.monitoring.unregister_event_listener(on_event)
+            traffic.close()
+        fresh.append(events["compiles"] - events["hits"])
+    assert fresh[1] >= 1        # not every program came from the cache
+    assert fresh[1] < fresh[0]  # but what does not close over the seed did
+
+
+class _Problem:
+    m, p, n, width = 8, 2048, 8192, 8192
+
+
+@pytest.mark.parametrize("chips", [1, 2, 4])
+def test_least_time_is_shared_by_the_chips_that_ran_ops(chips):
+    """``busy_s`` is the mean over the chips that ran ops, so the least time
+    it is compared with is the work's over as many chips."""
+    out = {"window_s": 1.0, "iters_to_tol": [],
+           "stats": {"served": 32, "padded": 0, "batches": 2, "shed": 0}}
+    summary = trace.Summary(busy_s=0.5, window_s=1.0, chips=chips,
+                            device_ops=[], idle_gaps=[])
+    peaks = work.load_peaks("TPU v5 lite")
+    run = harness.Run(cell="c", cfg={"iters": 10}, mix={},
+                      problem=_Problem(), out=out, setup_s=1.0,
+                      analyze_s=0.5, warm_s=0.5, trace=summary, peaks=peaks)
+    one = work.least_time(work.apc_iteration(
+        m=8, p=2048, n=8192, width=8192, k=16), peaks).seconds * 2 * 10
+    assert run.least_seconds() == pytest.approx(one / chips)
+    assert run.iter_roofline() == pytest.approx(100.0 * one / chips / 0.5)
 
 
 def test_a_metric_is_added_by_adding_a_file(tmp_path, f32):  # noqa: F811
@@ -170,4 +313,6 @@ def test_every_name_in_the_spec_has_its_file():
         assert callable(mod.read)
         if m in SPEC["per_layer"]:
             assert (mod.LAYER, mod.MOVES) == (m["layer"], m["moves"])
+    for c in SPEC["configs"]:
+        assert tiny_cfg(c["name"])["tiny"]
     json.dumps(SPEC)

@@ -5,10 +5,10 @@ each fault a cell can have, planted under the timed path of a whole run
 
 Faults: a step that returns its state unchanged; half of a batch left out
 (every other slot, the first included, keeps its initial state) where the
-batch holds more than one request; an answer altered where it is produced.
-The cell runs on one chip, so there is no exchange between chips to leave
-out; and half of the tall system's row blocks still determine x, so an
-exchange cut to half of the workers is no fault there.
+cell's mix batches more than one request; an answer altered where it is
+produced.  The cells run on one chip, so there is no exchange between
+chips to leave out; and half of the tall system's row blocks still
+determine x, so an exchange cut to half of the workers is no fault there.
 """
 from __future__ import annotations
 
@@ -18,20 +18,15 @@ import json
 import jax.numpy as jnp
 import pytest
 
-from bench_testkit import (CELLS, SECONDS, SPEC, STEPS, f32,  # noqa: F401
-                           tiny_files)
+from bench_testkit import (CELLS, SECONDS, SPEC, STEPS,  # noqa: F401
+                           check_control, f32, tiny_files)
 from bench import control, harness
 from repro.solvers.projection import APCSolver
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_control_is_not_correct(cell, f32, tmp_path):  # noqa: F811
-    r = control.control_run(cell, 11, SECONDS, steps=STEPS,
-                            files=tiny_files(tmp_path))
-    assert r["answers"] > 0
-    c = r["compared"]["max_rel_residual"]
-    assert c["value"] > c["limit"]
-    assert r["correct"] is False
+    check_control(cell, tiny_files(tmp_path))
 
 
 def test_closed_loop_control_is_not_correct(f32, tmp_path):  # noqa: F811
@@ -80,8 +75,16 @@ FAULTS = {
     "half_batch": ("step_many_residual", _half_batch),
     "altered": ("extract", _altered),
 }
-CASES = [("tall16k.open", "unchanged"), ("tall16k.open", "half_batch"),
-         ("tall16k.open", "altered")]
+
+
+def _faults(cell: str):
+    """The faults ``cell`` can have: half a batch only where its mix
+    batches more than one request."""
+    batch = int(harness.resolve(cell, SPEC).mix["batch"])
+    return [f for f in FAULTS if f != "half_batch" or batch > 1]
+
+
+CASES = [(cell, fault) for cell in CELLS for fault in _faults(cell)]
 
 
 @pytest.mark.parametrize("cell,fault", CASES)
