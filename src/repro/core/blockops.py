@@ -62,8 +62,7 @@ def bmatvec(A, x):
 def bmatvec_each(A, D):
     """Per-block matvec ``A_i d_i`` -> (m, p) for per-block ``(m, n)`` D."""
     if is_sparse(A):
-        d = jnp.take_along_axis(D, A.cols, axis=1)
-        return jnp.einsum("mpw,mw->mp", A.vals, d)
+        return jnp.einsum("mpw,mw->mp", A.vals, gather_support(A, D))
     return jnp.einsum("mpn,mn->mp", A, D)
 
 
@@ -77,11 +76,24 @@ def bmatvec_many(A, X):
 def brmatvec(A, u):
     """Per-block transpose matvec ``A_i^T u_i`` -> (m, n)."""
     if is_sparse(A):
-        contr = jnp.einsum("mpw,mp->mw", A.vals, u)
-        rows = jnp.arange(A.cols.shape[0])[:, None]
-        return jnp.zeros((A.cols.shape[0], ncols(A)), contr.dtype).at[
-            rows, A.cols].add(contr)
+        return scatter_support(A, jnp.einsum("mpw,mp->mw", A.vals, u))
     return jnp.einsum("mpn,mp->mn", A, u)
+
+
+def gather_support(A: SparseBlocks, D):
+    """Each block's support entries of per-block vectors: (..., m, n) D ->
+    (..., m, w) with ``[..., i, j] = D[..., i, cols[i, j]]``."""
+    rows = jnp.arange(A.cols.shape[0])[:, None]
+    return D[..., rows, A.cols]
+
+
+def scatter_support(A: SparseBlocks, C):
+    """The inverse placement: (..., m, w) values on each block's support,
+    scatter-added into zero (..., m, n) vectors (a padded slot's duplicate
+    index adds its value, which the operand keeps at zero)."""
+    rows = jnp.arange(A.cols.shape[0])[:, None]
+    return jnp.zeros(C.shape[:-1] + (ncols(A),), C.dtype).at[
+        ..., rows, A.cols].add(C)
 
 
 def brmatvec_sum(A, u):

@@ -23,6 +23,7 @@ A system carries two orthogonal tags beyond its blocks:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax.numpy as jnp
@@ -102,6 +103,15 @@ class BlockSystem:
         return blockops.SparseBlocks(
             vals=vals, cols=self.cols,
             span=jnp.zeros((self.n,), self.A_blocks.dtype))
+
+    @functools.cached_property
+    def support_fill(self) -> float:
+        """Nonzeros over the entries the operand stores: m·p·w for the
+        compressed support (w the padded support width), m·p·n dense.
+        Counted once on the device, then kept on the host."""
+        width = self.cols.shape[1] if self.is_sparse else self.n
+        nnz = int(jnp.count_nonzero(self.A_blocks))
+        return nnz / (self.m * self.p * width)
 
     @property
     def sparsity(self) -> float:

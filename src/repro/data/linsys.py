@@ -244,6 +244,41 @@ def sparse_matrix_market_proxy(key: str, m: Optional[int] = None, *,
     return as_sparse(_finalize(A, m, rng, dtype))
 
 
+def hpcg_stencil(nx: int, ny: int, nz: int, m: int, *,
+                 dtype=jnp.float64) -> BlockSystem:
+    """HPCG's operator (``GenerateProblem`` of the HPCG reference code): the
+    27-point stencil on an nx × ny × nz grid, split into m z-slabs.
+
+    Row ``i = ix + nx·(iy + ny·iz)`` holds 26 on the diagonal and −1 for
+    each of its up to 26 grid neighbours inside the grid, so boundary rows
+    are strictly diagonally dominant and A is SPD.  HPCG's exact solution
+    is all ones: ``x_true = 1`` and ``b = A·1``.  Each worker block is
+    ``nz / m`` whole z-planes of ``p = nx·ny·nz / m`` rows, whose column
+    support is its own planes and the neighbouring plane on each side.
+    """
+    if nz % m:
+        raise ValueError(f"m={m} z-slabs must divide nz={nz}")
+    n = nx * ny * nz
+    iz, iy, ix = np.meshgrid(np.arange(nz), np.arange(ny), np.arange(nx),
+                             indexing="ij")
+    iz, iy, ix = iz.ravel(), iy.ravel(), ix.ravel()   # row i's grid point
+    rows = np.arange(n)
+    A = np.zeros((n, n), np.dtype(dtype))
+    for dz in (-1, 0, 1):
+        for dy in (-1, 0, 1):
+            for dx in (-1, 0, 1):
+                jz, jy, jx = iz + dz, iy + dy, ix + dx
+                inside = ((0 <= jx) & (jx < nx) & (0 <= jy) & (jy < ny)
+                          & (0 <= jz) & (jz < nz))
+                cols = jx + nx * (jy + ny * jz)
+                A[rows[inside], cols[inside]] = -1.0
+    A[rows, rows] = 26.0
+    b = A.sum(axis=1, dtype=np.float64)              # A·1, exact
+    return as_sparse(partition(jnp.asarray(A), jnp.asarray(b, dtype=dtype),
+                               m, x_true=jnp.ones(n, dtype),
+                               mode="square"))
+
+
 ALL_PROBLEMS = {
     "qc324": lambda seed=0: matrix_market_proxy("qc324", seed=seed),
     "orsirr1": lambda seed=0: matrix_market_proxy("orsirr1", seed=seed),

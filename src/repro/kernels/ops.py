@@ -392,11 +392,11 @@ def _engine_candidates(family: str, p_pad: int, n_pad: int, k_pad: int,
     """The two engines ``_measure_engine`` times for one (p, n, k) shape,
     as zero-argument calls on dummy operands: ``fused`` (the kernel pair)
     and ``unfused`` (the XLA step the dispatch falls back to — the pinv
-    step for dense ``apc``, the Cholesky step otherwise).  Each is jitted
-    and ``vmap``-ed over a small dummy worker axis (``_MEAS_WORKERS``), the
-    way the solvers dispatch them.  Sparse families run the
-    compressed-support fused op and the unfused SparseBlocks-style step
-    on a random w-column support."""
+    step for ``apc`` and ``apc_sparse``, the Cholesky step for Cimmino).
+    Each is jitted and ``vmap``-ed over a small dummy worker axis
+    (``_MEAS_WORKERS``), the way the solvers dispatch them.  Sparse
+    families run the compressed-support fused op and the unfused
+    SparseBlocks-style step on a random w-column support."""
     rng = np.random.default_rng(0)
     mw = _MEAS_WORKERS
     if family.endswith("_sparse"):
@@ -439,15 +439,14 @@ def _engine_candidates(family: str, p_pad: int, n_pad: int, k_pad: int,
             def fused():
                 return fused_v(vals, cols, bvals)
 
-            def _unf(vi, ci, Li):
+            def _unf(vi, ci, bvi):
                 d = xbar - x
                 u = d[:, ci] @ vi.T
-                wsol = jax.scipy.linalg.cho_solve((Li, True), u.T).T
-                return (x + d).at[:, ci].add(-(wsol @ vi))
+                return (x + d).at[:, ci].add(-(u @ bvi.T))
             unfused_v = jax.jit(jax.vmap(_unf))
 
             def unfused():
-                return unfused_v(vals, cols, L)
+                return unfused_v(vals, cols, bvals)
     else:
         A = jnp.asarray(rng.standard_normal((mw, p_pad, n_pad)), dtype)
         G = (jnp.einsum("mpn,mqn->mpq", A, A)
@@ -780,24 +779,24 @@ def sparse_proj_update(vals, cols, bvals, x, xbar, gamma, *,
     x2, squeeze = _rows(x)
     xb2, _ = _rows(xbar)
     k = x2.shape[0]
-    xs = x2[:, cols]
-    xbs = xb2[:, cols]
     V2, _ = _pad_axis(vals, 0, 8)
     V2, _ = _pad_axis(V2, 1, 128)              # (p8, w128)
     Bv2, _ = _pad_axis(bvals, 1, 8)
     Bv2, _ = _pad_axis(Bv2, 0, 128)            # (w128, p8)
-    xs2 = _pad_rows(_pad_axis(xs, 1, 128)[0])
-    xbs2 = _pad_rows(_pad_axis(xbs, 1, 128)[0])
-    w_pad = V2.shape[1]
-    bw, bpp, bk = pick_tiles(w_pad, V2.shape[0], xs2.shape[0], vals.dtype,
-                             interpret=interpret)
-    u = bp.sparse_gather(V2, xs2, xbs2, bn=bw, bp=bpp, bk=bk,
-                         interpret=interpret)              # (k8, p8)
-    c = bp.sparse_scatter(Bv2, u, bn=bw, bp=bpp, bk=bk,
-                          interpret=interpret)             # (k8, w128)
+    with jax.named_scope("apc.gather"):
+        xs2 = _pad_rows(_pad_axis(x2[:, cols], 1, 128)[0])
+        xbs2 = _pad_rows(_pad_axis(xb2[:, cols], 1, 128)[0])
+        bw, bpp, bk = pick_tiles(V2.shape[1], V2.shape[0], xs2.shape[0],
+                                 vals.dtype, interpret=interpret)
+        u = bp.sparse_gather(V2, xs2, xbs2, bn=bw, bp=bpp, bk=bk,
+                             interpret=interpret)          # (k8, p8)
+    with jax.named_scope("apc.project"):
+        c = bp.sparse_scatter(Bv2, u, bn=bw, bp=bpp, bk=bk,
+                              interpret=interpret)         # (k8, w128)
     g = jnp.asarray(gamma, x2.dtype)
-    y = x2 + g * (xb2 - x2)
-    y = y.at[:, cols].add(-g * c[:k, :w].astype(y.dtype))
+    with jax.named_scope("apc.scatter"):
+        y = x2 + g * (xb2 - x2)
+        y = y.at[:, cols].add(-g * c[:k, :w].astype(y.dtype))
     u = u[:k, :p].astype(x2.dtype)
     return (y[0], u[0]) if squeeze else (y, u)
 

@@ -8,13 +8,15 @@ parameters from the Theorem-1 spectral analysis of X when none are given.
 Three engines apply the projection:
 
 - the Cholesky step (``core/apc.py``): two triangular solves against the
-  Gram's Cholesky factor per worker per iteration.  Runs under
-  ``use_kernel=False``, where the factors carry no pinv factor B, and
-  where the Pallas pair loses for Cimmino or a sparse operand;
-- the pinv step: under ``use_kernel=True`` the factors carry the dense B
-  (``kernel_factors``), and where the engine autotune (``kops.use_fused``)
-  says the Pallas pair loses, APC and consensus contract against B in
-  plain XLA, one pass over A and one over B with no triangular solve;
+  Gram's Cholesky factor per worker per iteration.  Runs for a dense
+  operand under ``use_kernel=False``, where the factors carry no pinv
+  factor B, and for Cimmino where the Pallas pair loses;
+- the pinv step: APC and consensus contract against B in plain XLA, one
+  pass over A and one over B with no triangular solve.  A dense operand
+  carries B under ``use_kernel=True`` (``kernel_factors``) and takes this
+  step where the engine autotune (``kops.use_fused``) says the Pallas pair
+  loses; a sparse operand always carries its support pinv
+  (``_sparse_factors``) and always takes it unless the pair wins;
 - the Pallas pair (``kernels/``), where the autotune says it wins.
 """
 from __future__ import annotations
@@ -25,6 +27,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+import scipy.linalg
 from jax.sharding import PartitionSpec as P
 
 from repro.core import blockops
@@ -32,6 +35,7 @@ from repro.core import spectral
 from repro.core import apc as apc_core
 from repro.core.apc import APCState, _gram_chol, _gram_solve
 from repro.core.partition import BlockSystem
+from repro.runtime.spans import span
 
 from .api import Solver
 from .registry import register
@@ -56,25 +60,43 @@ class ProjFactors(NamedTuple):
     A: jnp.ndarray      # (m, p, n) row blocks, or a blockops.SparseBlocks
     chol: jnp.ndarray   # (m, p, p) Cholesky of Gram A_i A_i^T
     B: Optional[jnp.ndarray] = None  # pinv factors A^T G^{-1}: (m, n, p)
-                                     # dense, (m, w, p) support-compressed
-                                     # for SparseBlocks operands; present
-                                     # under use_kernel=True only (see
-                                     # kernel_factors), where the Pallas
-                                     # pair and, dense, the XLA pinv step
-                                     # of APC/consensus read it
+                                     # dense, present under use_kernel=True
+                                     # only (see kernel_factors); (m, w, p)
+                                     # support-compressed for SparseBlocks
+                                     # operands, always (_sparse_factors)
 
 
 def _proj_prepare(A, jitter: float) -> ProjFactors:
     if blockops.is_sparse(A):
-        # support-compressed Gram — exact (padded columns carry zeros)
-        G = blockops.bgram(A)
-        if jitter:
-            p = G.shape[-1]
-            tr = jnp.trace(G, axis1=-2, axis2=-1)[:, None, None]
-            G = G + jitter * tr / p * jnp.eye(p, dtype=G.dtype)
-        return ProjFactors(A=A, chol=jnp.linalg.cholesky(G))
+        return _sparse_factors(A, jitter)
     chol = jax.vmap(lambda Ai: _gram_chol(Ai, jitter))(A)
     return ProjFactors(A=A, chol=chol)
+
+
+def _sparse_factors(A: blockops.SparseBlocks, jitter: float) -> ProjFactors:
+    """The factors of a SparseBlocks operand, made on the host in float64
+    and rounded once to the operand's dtype: the support Gram
+    G_i = V_i V_iᵀ (exact: padded columns carry zeros), its Cholesky
+    factor, and the support-compressed pinv Bvals_i = (G_i⁻¹ V_i)ᵀ (w, p).
+
+    The sparse step projects against Bvals with plain contractions, so no
+    triangular solve runs per iteration, and every answer's accuracy rests
+    on B alone: A_i x_i = b_i holds as well as A_i B_i = I, here to one
+    rounding of the float64 factor, whatever device the step runs on.
+    """
+    with span("repro.proj.sparse_factor"):
+        V = np.asarray(jax.device_get(A.vals), np.float64)      # (m, p, w)
+        G = V @ V.transpose(0, 2, 1)
+        p = G.shape[-1]
+        if jitter:
+            tr = np.trace(G, axis1=-2, axis2=-1)[:, None, None]
+            G = G + jitter * tr / p * np.eye(p)
+        L = np.linalg.cholesky(G)
+        Bt = np.stack([scipy.linalg.cho_solve((Li, True), Vi)
+                       for Li, Vi in zip(L, V)])                 # (m, p, w)
+        dt = A.vals.dtype
+        return ProjFactors(A=A, chol=jnp.asarray(L, dt),
+                           B=jnp.asarray(Bt.transpose(0, 2, 1), dt))
 
 
 def _with_pinv(factors: ProjFactors) -> ProjFactors:
@@ -84,7 +106,9 @@ def _with_pinv(factors: ProjFactors) -> ProjFactors:
     the block's column support, so Bvals_i = (G_i^{-1} vals_i)^T is the
     full factor stored as (w, p) on the same ``cols`` — padded support
     slots carry exact-zero vals columns and therefore exact-zero Bvals
-    rows, keeping every kernel contraction exact.
+    rows, keeping every kernel contraction exact.  Local sparse factors
+    come with it (``_sparse_factors``); the mesh backend's on-mesh
+    prepare forms it here.
     """
     if factors.B is not None:
         return factors
@@ -100,10 +124,12 @@ def _with_pinv(factors: ProjFactors) -> ProjFactors:
 
 def _min_norm_solutions(factors: ProjFactors, b: jnp.ndarray) -> jnp.ndarray:
     """x0_i = A_i^T (A_i A_i^T)^{-1} b_i — the min-norm local solutions;
-    B_i b_i where the dense pinv factor is present."""
+    B_i b_i where the pinv factor is present (always, for a sparse
+    operand: scattered from its support)."""
     if blockops.is_sparse(factors.A):
-        return blockops.brmatvec(factors.A,
-                                 _cho_solve_workers(factors.chol, b))
+        with jax.named_scope("apc.pinv"):
+            return blockops.scatter_support(
+                factors.A, jnp.einsum("mwp,mp->mw", factors.B, b))
     if factors.B is not None:
         with jax.named_scope("apc.pinv"):
             return jnp.einsum("mnp,mp->mn", factors.B, b)
@@ -123,6 +149,49 @@ def _pinv_update(factors: ProjFactors, x, xbar, gamma):
     with jax.named_scope("apc.pinv"):
         x_new = x + gamma * (d - jnp.einsum("mnp,...mp->...mn", factors.B, u))
     return x_new, u
+
+
+def _sparse_pinv_update(factors: ProjFactors, x, xbar, gamma):
+    """``_pinv_update`` for a SparseBlocks operand, on each block's column
+    support: (x_new, u) with u_i = V_i d_i[cols_i] (``apc.gather``),
+    c_i = Bvals_i u_i (``apc.project``) and x_new_i = x_i + γ d_i with
+    −γ c_i scatter-added on cols_i (``apc.scatter``).  Padded support slots
+    carry zero vals and zero Bvals rows, so they add exact zeros.
+    Batch-polymorphic as ``_pinv_update``."""
+    Asp = factors.A
+    d = xbar[..., None, :] - x
+    with jax.named_scope("apc.gather"):
+        u = jnp.einsum("mpw,...mw->...mp", Asp.vals,
+                       blockops.gather_support(Asp, d))
+    with jax.named_scope("apc.project"):
+        c = jnp.einsum("mwp,...mp->...mw", factors.B, u)
+    with jax.named_scope("apc.scatter"):
+        x_new = x + gamma * d + blockops.scatter_support(Asp, -gamma * c)
+    return x_new, u
+
+
+def _sparse_step_u(factors: ProjFactors, x, xbar, gamma, use_kernel: bool):
+    """One sparse APC worker update, (x_new, u): x (m, n) with x̄ (n,), or
+    x (k, m, n) with x̄ (k, n), and u (m, p) or (k, m, p).  The fused
+    compressed-support Pallas pair where ``use_kernel`` and the engine
+    autotune say it wins, else the support pinv step."""
+    Asp = factors.A
+    batched = x.ndim == 3
+    if use_kernel and _sparse_use_fused("apc_sparse", Asp,
+                                        x.shape[0] if batched else 1):
+        from repro.kernels import ops as kops
+
+        # one VMEM residency of the (p, w) vals / (w, p) Bvals tiles per
+        # worker, the k rows of a batch streamed through it
+        def worker(Vi, ci, Bvi, xi):
+            return kops.sparse_proj_update(Vi, ci, Bvi, xi, xbar, gamma)
+
+        X = jnp.swapaxes(x, 0, 1) if batched else x       # (m, [k,] n)
+        x_new, u = jax.vmap(worker)(Asp.vals, Asp.cols, factors.B, X)
+        if batched:
+            x_new, u = jnp.swapaxes(x_new, 0, 1), jnp.swapaxes(u, 0, 1)
+        return x_new, u
+    return _sparse_pinv_update(factors, x, xbar, gamma)
 
 
 def _cho_solve_workers(chol, u):
@@ -215,27 +284,8 @@ class APCSolver(Solver):
     def step(self, factors, b, state, params, *, use_kernel=False):
         gamma, eta = params["gamma"], params["eta"]
         if blockops.is_sparse(factors.A):
-            Asp = factors.A
-            if (use_kernel and factors.B is not None
-                    and _sparse_use_fused("apc_sparse", Asp, 1)):
-                from repro.kernels import ops as kops
-
-                # fused compressed-support pair: one VMEM residency of the
-                # (p, w) vals / (w, p) Bvals tiles per worker
-                def worker(Vi, ci, Bvi, xi):
-                    return kops.sparse_proj_update(Vi, ci, Bvi, xi,
-                                                   state.xbar, gamma)[0]
-
-                x_new = jax.vmap(worker)(Asp.vals, Asp.cols, factors.B,
-                                         state.x)
-            else:
-                # mask-aware products on the column support (same update
-                # as the unfused mesh formulation below)
-                d = state.xbar[None, :] - state.x
-                u = blockops.bmatvec_each(factors.A, d)
-                w = _cho_solve_workers(factors.chol, u)
-                proj = d - blockops.brmatvec(factors.A, w)
-                x_new = state.x + gamma * proj
+            x_new, _ = _sparse_step_u(factors, state.x, state.xbar, gamma,
+                                      use_kernel)
             xbar_new = (eta * jnp.mean(x_new, axis=0)
                         + (1.0 - eta) * state.xbar)
             return APCState(x=x_new, xbar=xbar_new, t=state.t + 1)
@@ -266,27 +316,17 @@ class APCSolver(Solver):
     def step_many(self, factors, Bb, states, params, *, use_kernel=False):
         """Fused multi-RHS iteration: the k batch rows stream through ONE
         VMEM residency of every A/B tile (states.x (k, m, n))."""
+        gamma, eta = params["gamma"], params["eta"]
+        if blockops.is_sparse(factors.A):
+            x_new, _ = _sparse_step_u(factors, states.x, states.xbar, gamma,
+                                      use_kernel)
+            xbar_new = (eta * jnp.mean(x_new, axis=1)
+                        + (1.0 - eta) * states.xbar)
+            return APCState(x=x_new, xbar=xbar_new, t=states.t + 1)
         if not (use_kernel and factors.B is not None):
             return super().step_many(factors, Bb, states, params,
                                      use_kernel=use_kernel)
         from repro.kernels import ops as kops
-        gamma, eta = params["gamma"], params["eta"]
-        if blockops.is_sparse(factors.A):
-            Asp = factors.A
-            if not _sparse_use_fused("apc_sparse", Asp, Bb.shape[0]):
-                return super().step_many(factors, Bb, states, params,
-                                         use_kernel=False)  # measured fb
-            X = jnp.swapaxes(states.x, 0, 1)              # (m, k, n)
-
-            def worker(Vi, ci, Bvi, Xi):
-                return kops.sparse_proj_update(Vi, ci, Bvi, Xi,
-                                               states.xbar, gamma)[0]
-
-            x_new = jnp.swapaxes(jax.vmap(worker)(
-                Asp.vals, Asp.cols, factors.B, X), 0, 1)  # (k, m, n)
-            xbar_new = (eta * jnp.mean(x_new, axis=1)
-                        + (1.0 - eta) * states.xbar)
-            return APCState(x=x_new, xbar=xbar_new, t=states.t + 1)
         if kops.use_fused("apc", factors.A.shape[1], factors.A.shape[2],
                           Bb.shape[0], factors.A.dtype):
             X = jnp.swapaxes(states.x, 0, 1)              # (m, k, n)
@@ -317,28 +357,12 @@ class APCSolver(Solver):
     def _step_u(self, factors, state, gamma):
         """One worker update plus the gather result u (the residual
         source); engine dispatch identical to ``step``."""
-        kern = factors.B is not None
-        sparse = blockops.is_sparse(factors.A)
-        if kern:
-            if sparse:
-                kern = _sparse_use_fused("apc_sparse", factors.A, 1)
-            else:
-                from repro.kernels import ops as kops
-                kern = kops.use_fused("apc", factors.A.shape[1],
-                                      factors.A.shape[2], 1,
-                                      factors.A.dtype)
-        if kern and sparse:
-            from repro.kernels import ops as kops
-            Asp = factors.A
-
-            def worker(Vi, ci, Bvi, xi):
-                return kops.sparse_proj_update(Vi, ci, Bvi, xi,
-                                               state.xbar, gamma)
-
-            x_new, u = jax.vmap(worker)(Asp.vals, Asp.cols, factors.B,
-                                        state.x)
-        elif kern:
-            from repro.kernels import ops as kops
+        if blockops.is_sparse(factors.A):
+            return _sparse_step_u(factors, state.x, state.xbar, gamma, True)
+        from repro.kernels import ops as kops
+        if factors.B is not None and kops.use_fused(
+                "apc", factors.A.shape[1], factors.A.shape[2], 1,
+                factors.A.dtype):
             u = jax.vmap(
                 lambda Ai, xi: kops.proj_gather(Ai, xi, state.xbar))(
                     factors.A, state.x)                   # (m, p)
@@ -346,7 +370,7 @@ class APCSolver(Solver):
                 lambda Bi, xi, ui: kops.proj_scatter(Bi, xi, state.xbar,
                                                      ui, gamma))(
                     factors.B, state.x, u)
-        elif factors.B is not None and not sparse:
+        elif factors.B is not None:
             x_new, u = _pinv_update(factors, state.x, state.xbar, gamma)
         else:
             d = state.xbar[None, :] - state.x
@@ -368,40 +392,28 @@ class APCSolver(Solver):
     @_scoped("apc.step")
     def step_many_residual(self, factors, Bb, states, params):
         gamma, eta = params["gamma"], params["eta"]
-        kern = factors.B is not None
+        from repro.kernels import ops as kops
         sparse = blockops.is_sparse(factors.A)
-        k = Bb.shape[0]
-        if kern:
-            if sparse:
-                kern = _sparse_use_fused("apc_sparse", factors.A, k)
-            else:
-                from repro.kernels import ops as kops
-                kern = kops.use_fused("apc", factors.A.shape[1],
-                                      factors.A.shape[2], k,
-                                      factors.A.dtype)
-        if kern:
-            from repro.kernels import ops as kops
+        kern = (not sparse and factors.B is not None
+                and kops.use_fused("apc", factors.A.shape[1],
+                                   factors.A.shape[2], Bb.shape[0],
+                                   factors.A.dtype))
+        if sparse:
+            x_new, u = _sparse_step_u(factors, states.x, states.xbar, gamma,
+                                      True)
+            rsq = jnp.sum(u * u, axis=(1, 2))             # (k,)
+        elif kern:
             X = jnp.swapaxes(states.x, 0, 1)              # (m, k, n)
-            if sparse:
-                Asp = factors.A
-
-                def worker(Vi, ci, Bvi, Xi):
-                    return kops.sparse_proj_update(Vi, ci, Bvi, Xi,
-                                                   states.xbar, gamma)
-
-                x_new, u = jax.vmap(worker)(Asp.vals, Asp.cols,
-                                            factors.B, X)
-            else:
-                u = jax.vmap(
-                    lambda Ai, Xi: kops.proj_gather(Ai, Xi, states.xbar))(
-                        factors.A, X)                     # (m, k, p)
-                x_new = jax.vmap(
-                    lambda Bi, Xi, ui: kops.proj_scatter(
-                        Bi, Xi, states.xbar, ui, gamma))(
-                            factors.B, X, u)              # (m, k, n)
+            u = jax.vmap(
+                lambda Ai, Xi: kops.proj_gather(Ai, Xi, states.xbar))(
+                    factors.A, X)                         # (m, k, p)
+            x_new = jax.vmap(
+                lambda Bi, Xi, ui: kops.proj_scatter(
+                    Bi, Xi, states.xbar, ui, gamma))(
+                        factors.B, X, u)                  # (m, k, n)
             x_new = jnp.swapaxes(x_new, 0, 1)             # (k, m, n)
             rsq = jnp.sum(u * u, axis=(0, 2))             # (k,)
-        elif factors.B is not None and not sparse:
+        elif factors.B is not None:
             x_new, u = _pinv_update(factors, states.x, states.xbar, gamma)
             rsq = jnp.sum(u * u, axis=(1, 2))             # (k,)
         else:

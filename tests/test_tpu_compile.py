@@ -106,3 +106,72 @@ def test_kernel_compiles_for_v5e(one_chip, compile_env, op, shape):
     assert kops.tile_fits(bn, bp_, bk, np.dtype(dt))
     if (p, dt) == (4096, jnp.float32):
         assert (bn, bp_, bk) == (512, 2048, k)   # whole p does not fit
+
+
+# ---------------------------------------------------------------------------
+# The sparse step at the HPCG stencil's widths (16 x 16 x 32 in 4 z-slabs:
+# p = 2048 rows, support padded to w = 2560, n = 8192)
+# ---------------------------------------------------------------------------
+
+STENCIL = dict(m=4, p=2048, w=2560, n=8192)
+# the TPU's triangular solve: blocked against the diagonal-block inverses
+# this custom call forms
+TRSM_INVERSE = "InvertDiagBlocksLowerTriangular"
+
+
+def _sparse_factors_spec(S, m, p, w, n):
+    from repro.core import blockops
+    from repro.solvers.projection import ProjFactors
+    A = blockops.SparseBlocks(vals=S((m, p, w)), cols=S((m, w), jnp.int32),
+                              span=S((n,)))
+    return ProjFactors(A=A, chol=S((m, p, p)), B=S((m, w, p)))
+
+
+def test_the_tpu_triangular_solve_runs_on_diagonal_block_inverses(
+        one_chip, compile_env):
+    """What the sparse step ran each iteration before it moved to the
+    support pinv: ``cho_solve`` compiles for the TPU to a blocked solve on
+    the inverses of the factor's diagonal blocks, formed in a custom
+    call, so the next test can tell that solve from its absence."""
+    p = STENCIL["p"]
+
+    def S(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def solve(L, u):
+        return jax.scipy.linalg.cho_solve((L, True), u)
+
+    text = jax.jit(solve).lower(S((p, p)), S((p,))).compile().as_text()
+    assert TRSM_INVERSE in text
+
+
+@pytest.mark.parametrize("engine", ["unfused", "fused"])
+def test_sparse_served_scan_compiles_for_v5e_on_the_support_pinv(
+        one_chip, compile_env, monkeypatch, engine):
+    """The served sparse program (the executor's init and scan of
+    ``step_many_residual`` at k = 1) compiles at the stencil's widths with
+    either engine, and no triangular solve is left in it: the step projects
+    against the support pinv that ``_sparse_factors`` makes on the host."""
+    from repro import solvers
+    from repro.solvers import api
+    monkeypatch.setenv(kops.ENGINE_ENV, engine)
+    monkeypatch.setattr(kops.bp, "default_interpret", lambda: False)
+    m, p, n = STENCIL["m"], STENCIL["p"], STENCIL["n"]
+    apc = solvers.get("apc")
+    prm = {"gamma": 1.1, "eta": 3.0}
+
+    def S(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def served(factors, Bb):
+        states = jax.vmap(lambda b: apc.init(factors, b, prm))(Bb)
+        return api._history_scan_many(
+            None, apc.extract, factors, Bb, states, factors.A, 150,
+            step_many_residual=lambda f, bb, st: apc.step_many_residual(
+                f, bb, st, prm))
+
+    factors = _sparse_factors_spec(S, **STENCIL)
+    text = jax.jit(served).lower(factors, S((1, m, p))).compile().as_text()
+    assert TRSM_INVERSE not in text
+    assert "triangular_solve" not in text and "triangular-solve" not in text
+    assert ("tpu_custom_call" in text) == (engine == "fused")
